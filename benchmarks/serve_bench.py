@@ -828,6 +828,8 @@ def main(argv=None):
                     help="fraction of requests to trace (default 1.0 when "
                          "--trace is given, else 0 = off)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.trace_path or args.trace_sample is not None:
         from repro.obs import trace as obs_trace

@@ -1,4 +1,4 @@
-"""qwen1.5-4b — dense MHA with QKV bias.  [hf:Qwen/Qwen1.5-0.5B; hf]"""
+"""qwen1.5-4b — dense MHA with QKV bias.  [hf:Qwen/Qwen1.5-4B; hf]"""
 from .base import ModelConfig
 
 CONFIG = ModelConfig(

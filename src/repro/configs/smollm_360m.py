@@ -1,4 +1,4 @@
-"""smollm-360m — llama-arch small.  [hf:HuggingFaceTB/SmolLM-135M; hf]"""
+"""smollm-360m — llama-arch small.  [hf:HuggingFaceTB/SmolLM-360M; hf]"""
 from .base import ModelConfig
 
 CONFIG = ModelConfig(

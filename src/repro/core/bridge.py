@@ -74,6 +74,13 @@ class Bridge:
 
 _STATIC_TYPES = (bool, int, float, str, bytes)
 
+# What tracing raises when a task is plain Python rather than JAX code: such
+# a task deploys to the generic worker.  Every other error of the AOT path —
+# a bug in the task, a lowering or compiler refusal — propagates.
+NOT_TRACEABLE = (jax.errors.ConcretizationTypeError,
+                 jax.errors.TracerArrayConversionError,
+                 jax.errors.TracerIntegerConversionError)
+
 
 def _is_static_capture(v: Any) -> bool:
     """Compile-time constant vs. dynamic payload input.

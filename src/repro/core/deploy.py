@@ -19,7 +19,8 @@ from typing import Any, Callable
 
 import jax
 
-from .bridge import Bridge, make_executor_aot, make_executor_generic
+from .bridge import (NOT_TRACEABLE, Bridge, make_executor_aot,
+                     make_executor_generic)
 from .codeship import freeze_function
 from .config import DEFAULT_CONFIG, FunctionConfig
 from .function import RemoteFunction, data_captures
@@ -165,7 +166,7 @@ class Deployment:
             try:
                 executor = make_executor_aot(rf, *payload)
                 kind = "aot_xla"
-            except Exception:
+            except NOT_TRACEABLE:
                 executor = make_executor_generic(rf)
         else:
             executor = make_executor_generic(rf)
@@ -222,6 +223,12 @@ class Deployment:
 
     def get(self, name: str) -> DeployedFunction:
         return self._functions[name]
+
+    @property
+    def functions(self) -> tuple[DeployedFunction, ...]:
+        """Every function deployed so far."""
+        with self._lock:
+            return tuple(self._functions.values())
 
     def __contains__(self, name: str) -> bool:
         return name in self._functions

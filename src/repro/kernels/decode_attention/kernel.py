@@ -7,10 +7,10 @@ matmul, amortizing the K/V read across the group (the TPU adaptation of
 GPU flash-decode, where warps split the cache instead).
 
 Layout: q (B, Hkv, G, D); k, v (B, Hkv, Skv, D); (kv_start, kv_len) as a
-(B, 2) int32 bounds plane in SMEM — start masks left-pad cache slots from
-ragged prefill, len bounds the live suffix.
+(B, 2) int32 bounds plane, a scalar-prefetch operand in SMEM — start masks
+left-pad cache slots from ragged prefill, len bounds the live suffix.
 Grid (B, Hkv, Skv/bk) — kv dim minor-most/sequential; running softmax state
-in VMEM scratch.
+in VMEM scratch (the prefill kernel's lane-replicated layout).
 """
 from __future__ import annotations
 
@@ -21,60 +21,67 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
+from ..flash_attention.kernel import (LANES, softmax_finish, softmax_init,
+                                      softmax_update)
 
 
-def _dec_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                scale, window, block_k, kv_blocks):
+def _tile_live(col0, width, kv_start, kv_len, window):
+    live = col0 < kv_len
+    live &= col0 + width > kv_start          # tile fully inside the left pad
+    if window:
+        live &= col0 + width > kv_len - window
+    return live
+
+
+def _decode_tile(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *, scale, col0,
+                 kv_start, kv_len, window):
+    q = q_ref[0, 0].astype(jnp.float32) * scale          # (G, D)
+    k = k_ref[0, 0].astype(jnp.float32)                  # (bk, D)
+    v = v_ref[0, 0].astype(jnp.float32)                  # (bk, D)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)  # (G,bk)
+    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    mask = (cols < kv_len) & (cols >= kv_start)
+    if window:
+        mask &= cols >= kv_len - window
+    softmax_update(s, mask, v, acc_ref, m_ref, l_ref)
+
+
+def _dec_kernel(bounds_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
+                l_ref, *, scale, window, block_k, kv_blocks):
+    b_ = pl.program_id(0)
     ik = pl.program_id(2)
-    kv_start = len_ref[0, 0]                 # first valid slot (left pad end)
-    kv_len = len_ref[0, 1]
+    kv_start = bounds_ref[b_, 0]             # first valid slot (left pad end)
+    kv_len = bounds_ref[b_, 1]
 
     @pl.when(ik == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        softmax_init(acc_ref, m_ref, l_ref)
 
     col0 = ik * block_k
-    live = col0 < kv_len
-    live &= col0 + block_k > kv_start        # tile fully inside the left pad
-    if window:
-        live &= col0 + block_k > kv_len - window
 
-    @pl.when(live)
+    @pl.when(_tile_live(col0, block_k, kv_start, kv_len, window))
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # (G, D)
-        k = k_ref[0, 0].astype(jnp.float32)                  # (bk, D)
-        v = v_ref[0, 0].astype(jnp.float32)                  # (bk, D)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (G,bk)
-        cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = (cols < kv_len) & (cols >= kv_start)
-        if window:
-            mask &= cols >= kv_len - window
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        # no valid col so far (m_new == NEG_INF, e.g. kv_start >= kv_len)
-        # must contribute 0, not exp(NEG_INF - NEG_INF) = 1 per col —
-        # keeps l at 0 so _fin zeroes the row, matching the ref path
-        p = jnp.where((m_new > 0.5 * NEG_INF)[:, None], p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1)
-        m_ref[...] = m_new
-        acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                        + jax.lax.dot_general(
-                            p, v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32))
+        _decode_tile(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, scale=scale,
+                     col0=col0, kv_start=kv_start, kv_len=kv_len,
+                     window=window)
 
     @pl.when(ik == kv_blocks - 1)
     def _fin():
-        l = l_ref[...]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        softmax_finish(acc_ref, l_ref, o_ref)
+
+
+def _scratch(g, d):
+    return [pltpu.VMEM((g, d), jnp.float32),
+            pltpu.VMEM((g, LANES), jnp.float32),
+            pltpu.VMEM((g, LANES), jnp.float32)]
+
+
+def _bounds(b, kv_len, kv_start):
+    if kv_start is None:
+        kv_start = jnp.zeros((b,), jnp.int32)
+    return jnp.stack([kv_start.astype(jnp.int32),
+                      kv_len.astype(jnp.int32)], axis=1)      # (B, 2)
 
 
 @functools.partial(
@@ -96,32 +103,29 @@ def flash_decode_bhgd(q, k, v, kv_len, kv_start=None, *, window=0, scale=None,
         skv += tail
     scale = scale if scale is not None else d ** -0.5
     kv_blocks = skv // block_k
-    if kv_start is None:
-        kv_start = jnp.zeros((b,), jnp.int32)
-    bounds = jnp.stack([kv_start.astype(jnp.int32),
-                        kv_len.astype(jnp.int32)], axis=1)    # (B, 2) SMEM
 
     kernel = functools.partial(_dec_kernel, scale=scale, window=window,
                                block_k=block_k, kv_blocks=kv_blocks)
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,          # bounds — SMEM, whole array
         grid=(b, hkv, kv_blocks),
         in_specs=[
-            pl.BlockSpec((1, 2), lambda b_, h, j: (b_, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, g, d), lambda b_, h, j: (b_, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b_, h, j: (b_, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b_, h, j: (b_, h, j, 0)),
+            pl.BlockSpec((1, 1, g, d), lambda b_, h, j, bnd: (b_, h, 0, 0)),
+            pl.BlockSpec((1, 1, block_k, d),
+                         lambda b_, h, j, bnd: (b_, h, j, 0)),
+            pl.BlockSpec((1, 1, block_k, d),
+                         lambda b_, h, j, bnd: (b_, h, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda b_, h, j: (b_, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, g, d),
+                               lambda b_, h, j, bnd: (b_, h, 0, 0)),
+        scratch_shapes=_scratch(g, d),
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, d), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-        ],
         interpret=interpret,
-    )(bounds, q, k, v)
+    )(_bounds(b, kv_len, kv_start), q, k, v)
 
 
 def _dec_paged_kernel(table_ref, bounds_ref, q_ref, k_ref, v_ref, o_ref,
@@ -140,46 +144,19 @@ def _dec_paged_kernel(table_ref, bounds_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(ik == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        softmax_init(acc_ref, m_ref, l_ref)
 
     col0 = ik * block_size
-    live = col0 < kv_len
-    live &= col0 + block_size > kv_start
-    if window:
-        live &= col0 + block_size > kv_len - window
 
-    @pl.when(live)
+    @pl.when(_tile_live(col0, block_size, kv_start, kv_len, window))
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # (G, D)
-        k = k_ref[0, 0].astype(jnp.float32)                  # (bs, D)
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = (cols < kv_len) & (cols >= kv_start)
-        if window:
-            mask &= cols >= kv_len - window
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where((m_new > 0.5 * NEG_INF)[:, None], p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1)
-        m_ref[...] = m_new
-        acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                        + jax.lax.dot_general(
-                            p, v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32))
+        _decode_tile(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, scale=scale,
+                     col0=col0, kv_start=kv_start, kv_len=kv_len,
+                     window=window)
 
     @pl.when(ik == table_width - 1)
     def _fin():
-        l = l_ref[...]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        softmax_finish(acc_ref, l_ref, o_ref)
 
 
 @functools.partial(
@@ -199,10 +176,6 @@ def flash_decode_paged_bhgd(q, k_pool, v_pool, table, kv_len, kv_start=None,
     nb, _, bs, _ = k_pool.shape
     t = table.shape[1]
     scale = scale if scale is not None else d ** -0.5
-    if kv_start is None:
-        kv_start = jnp.zeros((b,), jnp.int32)
-    bounds = jnp.stack([kv_start.astype(jnp.int32),
-                        kv_len.astype(jnp.int32)], axis=1)    # (B, 2)
 
     kernel = functools.partial(_dec_paged_kernel, scale=scale, window=window,
                                block_size=bs, table_width=t)
@@ -223,15 +196,12 @@ def flash_decode_paged_bhgd(q, k_pool, v_pool, table, kv_len, kv_start=None,
         out_specs=pl.BlockSpec((1, 1, g, d),
                                lambda b_, h, j, table_ref, bounds_ref:
                                (b_, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, d), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-        ],
+        scratch_shapes=_scratch(g, d),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         interpret=interpret,
-    )(table.astype(jnp.int32), bounds, q, k_pool, v_pool)
+    )(table.astype(jnp.int32), _bounds(b, kv_len, kv_start), q, k_pool,
+      v_pool)

@@ -31,7 +31,7 @@ def decode_attention(q, k, v, kv_len, *, window: int = 0,
     b, hq, d = q.shape
     _, skv, hkv, _ = k.shape
     g = hq // hkv
-    bk = min(block_k, max(128, skv))
+    bk = min(block_k, 128 * -(-skv // 128))      # whole 128-lane tiles
 
     qg = q.reshape(b, hkv, g, d)
     kt = jnp.swapaxes(k, 1, 2)                       # (B,Hkv,Skv,D)

@@ -21,6 +21,53 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
+# Running max and sum live lane-replicated in (rows, LANES) f32 scratch, the
+# TPU flash layout: Mosaic lays out no 1-D vector of rows.
+LANES = 128
+
+
+def _lanes(x, n):
+    """Widen a lane-replicated (R, LANES) value to (R, n) by slicing or
+    tiling whole vregs."""
+    if n <= LANES:
+        return x[:, :n]
+    assert n % LANES == 0, f"tile width {n} is not a multiple of {LANES}"
+    return jnp.tile(x, (1, n // LANES))
+
+
+def softmax_init(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def softmax_update(s, mask, v, acc_ref, m_ref, l_ref):
+    """One online-softmax step over a tile: scores ``s`` (R, bk) f32, its
+    validity ``mask``, values ``v`` (bk, D) f32.  Shared by the prefill and
+    both decode kernels."""
+    bk, d = v.shape
+    s = jnp.where(mask, s, NEG_INF)
+    m_prev, l_prev = m_ref[...], l_ref[...]               # (R, LANES)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    m_s = _lanes(m_new, bk)
+    p = jnp.exp(s - m_s)
+    # a row with NO valid col so far (m_new == NEG_INF: a pad query sharing
+    # a live tile with real rows, or kv_start >= kv_len) must contribute 0,
+    # not exp(NEG_INF - NEG_INF) = 1 per col — keeps l at 0 so the finish
+    # step zeroes it, matching the ref path
+    p = jnp.where(m_s > 0.5 * NEG_INF, p, 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+    m_ref[...] = m_new
+    acc_ref[...] = (acc_ref[...] * _lanes(alpha, d)
+                    + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                                          preferred_element_type=jnp.float32))
+
+
+def softmax_finish(acc_ref, l_ref, o_ref):
+    l = _lanes(l_ref[...], acc_ref.shape[1])
+    l = jnp.where(l == 0.0, 1.0, l)         # fully-masked rows -> 0 output
+    o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def _fa_kernel(start_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
@@ -28,13 +75,11 @@ def _fa_kernel(start_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
                kv_blocks, kv_valid):
     iq = pl.program_id(2)
     ik = pl.program_id(3)
-    kv_start = start_ref[0, 0]              # left-pad count for this row
+    kv_start = start_ref[pl.program_id(0)]  # left-pad count for this row
 
     @pl.when(ik == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        softmax_init(acc_ref, m_ref, l_ref)
 
     # absolute coordinates of this tile
     row0 = iq * block_q + q_offset          # first absolute q position
@@ -64,29 +109,11 @@ def _fa_kernel(start_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
             mask &= cols <= rows
         if window:
             mask &= cols > rows - window
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_ref[...]
-        l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        # a q row with NO valid col so far (m_new == NEG_INF: a pad query
-        # sharing a live tile with real rows) must contribute 0, not
-        # exp(NEG_INF - NEG_INF) = 1 per col — keeps l at 0 so _fin zeroes it
-        p = jnp.where((m_new > 0.5 * NEG_INF)[:, None], p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1)
-        m_ref[...] = m_new
-        acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                        + jax.lax.dot_general(
-                            p, v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32))
+        softmax_update(s, mask, v, acc_ref, m_ref, l_ref)
 
     @pl.when(ik == kv_blocks - 1)
     def _fin():
-        l = l_ref[...]
-        l = jnp.where(l == 0.0, 1.0, l)     # fully-masked rows -> 0 output
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        softmax_finish(acc_ref, l_ref, o_ref)
 
 
 @functools.partial(
@@ -99,7 +126,8 @@ def flash_attention_bhsd(q, k, v, kv_start=None, *, causal=True, window=0,
 
     ``q_offset``: absolute position of q[0] on the kv timeline.
     ``kv_start`` (B,) int32: per-row left-pad count — kv positions before it
-    are masked out (ragged-batch prefill).  None = no padding.
+    are masked out (ragged-batch prefill).  None = no padding.  It rides in
+    as a scalar-prefetch operand (whole array in SMEM).
     """
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape
@@ -115,25 +143,28 @@ def flash_attention_bhsd(q, k, v, kv_start=None, *, causal=True, window=0,
         q_offset=q_offset, block_q=block_q, block_k=block_k,
         kv_blocks=kv_blocks, kv_valid=skv)
 
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(b, hq, sq // block_q, kv_blocks),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b_, h, i, j: (b_, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i, j: (b_, h, i, 0)),
+            pl.BlockSpec((1, 1, block_q, d),
+                         lambda b_, h, i, j, st: (b_, h, i, 0)),
             pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h, i, j, g_=g: (b_, h // g_, j, 0)),
+                         lambda b_, h, i, j, st: (b_, h // g, j, 0)),
             pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h, i, j, g_=g: (b_, h // g_, j, 0)),
+                         lambda b_, h, i, j, st: (b_, h // g, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d),
-                               lambda b_, h, i, j: (b_, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
+                               lambda b_, h, i, j, st: (b_, h, i, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, LANES), jnp.float32),
+            pltpu.VMEM((block_q, LANES), jnp.float32),
         ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
         interpret=interpret,
-    )(kv_start.reshape(b, 1).astype(jnp.int32), q, k, v)
+    )(kv_start.astype(jnp.int32), q, k, v)

@@ -33,8 +33,10 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0,
     are masked out on every impl (ragged-batch prefill).  A fully masked
     row (start == Skv) yields finite output, never NaN.
 
-    impl: "ref" (jnp oracle) | "pallas" (TPU) | "pallas_interpret" (CPU
-    execution of the kernel body, used by the allclose test sweeps).
+    impl: "ref" (jnp oracle) | "xla" (query-chunked jnp) | "pallas" (TPU) |
+    "pallas_interpret" (CPU execution of the kernel body, used by the
+    allclose test sweeps).  ``kv_len`` (B,) bounds the valid keys per row
+    on the jnp impls only; the Pallas impls raise on it.
     """
     if impl == "ref":
         return attention_ref(q, k, v, causal=causal, window=window,
@@ -45,11 +47,10 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0,
                              q_offset=q_offset, scale=scale, kv_len=kv_len,
                              kv_start=kv_start)
     if kv_len is not None:
-        # the Pallas prefill kernel has no kv_len operand (chunked prefill
-        # runs on the xla path today); fall back to the oracle
-        return attention_ref(q, k, v, causal=causal, window=window,
-                             q_offset=q_offset, scale=scale, kv_len=kv_len,
-                             kv_start=kv_start)
+        raise ValueError(
+            f"attention impl={impl!r} takes no kv_len: the Pallas prefill "
+            "kernel bounds keys by Skv only — pick impl='ref' or 'xla' at "
+            "the call site")
 
     interpret = impl == "pallas_interpret"
     b, sq, hq, d = q.shape

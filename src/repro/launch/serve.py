@@ -20,6 +20,10 @@ instead: N engine-loop members, each pinned to its own worker, with
 prefix-aware routing (``--fleet-policy prefix|p2c|random``), optional
 prefill/decode disaggregation (``--fleet-disaggregate``), and elastic
 scale-up/drain (``--fleet-elastic``).
+
+On a TPU host this process initialises the params and so holds the chip;
+worker processes could not get it.  The launcher therefore refuses the
+backends that spawn them there (``CHILD_PROCESS_BACKENDS``).
 """
 from __future__ import annotations
 
@@ -34,6 +38,10 @@ from ..cloud import Session, available_backends
 from ..configs import get_config, get_smoke
 from ..models import build_model
 from ..runtime.server import LMServer, Request
+from .compile_cache import enable_compile_cache
+
+# backends whose workers run in child processes of this one
+CHILD_PROCESS_BACKENDS = ("processes", "http", "http-aio")
 
 
 def main():
@@ -85,6 +93,12 @@ def main():
     ap.add_argument("--fleet-prefill", type=int, default=1,
                     help="disaggregated mode: prefill member count")
     args = ap.parse_args()
+    if args.backend in CHILD_PROCESS_BACKENDS \
+            and jax.default_backend() == "tpu":
+        ap.error(f"--backend {args.backend} runs workers in child processes, "
+                 "and on a TPU host this process holds the chip: use "
+                 "--backend threads or inline")
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)

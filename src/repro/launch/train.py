@@ -13,6 +13,7 @@ import json
 
 from ..configs import get_config, get_smoke
 from ..runtime.trainer import train
+from .compile_cache import enable_compile_cache
 
 
 def main():
@@ -30,6 +31,7 @@ def main():
                     help="inject simulated preemptions at these steps")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
 

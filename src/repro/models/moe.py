@@ -20,29 +20,12 @@ and the code path is identical.
 from __future__ import annotations
 
 import functools
-import inspect
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from .layers import dense_init
-
-# jax moved shard_map to the top level and later renamed its replication-
-# check kwarg (check_rep -> check_vma) in separate releases, so resolve the
-# symbol and the kwarg independently: location by hasattr, kwarg by
-# signature (jax 0.5-0.6 has top-level shard_map but still check_rep).
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - exercised on jax 0.4.x installs
-    from jax.experimental.shard_map import shard_map as _shard_map
-try:
-    _SHARD_MAP_KW = (
-        {"check_vma": False}
-        if "check_vma" in inspect.signature(_shard_map).parameters
-        else {"check_rep": False})
-except (TypeError, ValueError):  # pragma: no cover - unintrospectable
-    _SHARD_MAP_KW = {}
 
 
 def moe_init(key, d_model: int, d_ff: int, n_experts: int, act: str, dtype,
@@ -245,22 +228,22 @@ def moe_apply(p, x, *, n_experts: int, top_k: int, capacity_factor: float,
             _moe_local_ep, n_experts=n_experts, top_k=top_k,
             capacity_factor=capacity_factor, act=act,
             model_axis=model_axis, token_axes=token_axes, model_size=msize)
-        y, aux, zloss, drop = _shard_map(
+        y, aux, zloss, drop = jax.shard_map(
             body, mesh=mesh,
             in_specs=(pspec, P(bdim, model_axis, None)),
             out_specs=(P(bdim, model_axis, None), P(), P(), P()),
-            **_SHARD_MAP_KW,
+            check_vma=False,
         )(p, x)
     else:
         body = functools.partial(
             _moe_local, n_experts=n_experts, top_k=top_k,
             capacity_factor=capacity_factor, act=act,
             model_axis=model_axis, token_axes=token_axes)
-        y, aux, zloss, drop = _shard_map(
+        y, aux, zloss, drop = jax.shard_map(
             body, mesh=mesh,
             in_specs=(pspec, P(bdim, None, None)),
             out_specs=(P(bdim, None, None), P(), P(), P()),
-            **_SHARD_MAP_KW,
+            check_vma=False,
         )(p, x)
     metrics = {"moe_aux": aux, "moe_zloss": zloss, "moe_drop": drop}
     return y, metrics

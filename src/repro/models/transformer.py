@@ -75,10 +75,14 @@ def _embed_in(p, cfg, tokens, embeds):
 def _logits(p, cfg, x):
     x = rms_norm(x, p["final_norm"], cfg.rms_eps)
     table = p["embed"] if cfg.tie_embeddings else p["unembed"]
-    out = jnp.einsum("...d,vd->...v", x, table)
+    # fp32 logits come straight out of the f32 accumulator: rounding them to
+    # the param dtype first ties near-equal greedy candidates
+    out = jnp.einsum("...d,vd->...v", x, table,
+                     preferred_element_type=jnp.float32 if cfg.logits_fp32
+                     else None)
     out = shard(out, "act_batch", "act_seq", "act_vocab") if out.ndim == 3 \
         else shard(out, "act_batch", "act_vocab")
-    return out.astype(jnp.float32) if cfg.logits_fp32 else out
+    return out
 
 
 def _ffn(lp, cfg: ModelConfig, h, dropless: bool = False):

@@ -99,7 +99,7 @@ class WorkerHost:
         AOT specialization needs example arguments; the first invocation's
         payload provides them (and pays the compile, i.e. the cold start).
         """
-        from ..core.bridge import (Bridge, make_executor_aot,
+        from ..core.bridge import (NOT_TRACEABLE, Bridge, make_executor_aot,
                                    make_executor_generic)
         fn = thaw_function(entry.code)
         rf = RemoteFunction(fn, name=entry.human_name, config=entry.config,
@@ -110,7 +110,7 @@ class WorkerHost:
             try:
                 executor = make_executor_aot(rf, args, kwargs, captures)
                 kind = "aot_xla"
-            except Exception:
+            except NOT_TRACEABLE:
                 executor = make_executor_generic(rf)
         else:
             executor = make_executor_generic(rf)

@@ -144,6 +144,32 @@ def test_deploy_generic_worker_fallback():
     assert d.bridge.kind == "generic_worker"
 
 
+def pallas_task(q):
+    from repro.kernels.flash_attention import attention
+    return attention(q, q, q, impl="pallas")
+
+
+def host_only_task(x):
+    return float(x.sum())
+
+
+def test_deploy_surfaces_lowering_errors():
+    """A traceable task whose lowering fails — here a compiled (not
+    interpreted) Pallas kernel on the CPU — raises at deploy; it is never
+    demoted to the eager generic worker."""
+    with pytest.raises(ValueError, match="interpret mode"):
+        Deployment().deploy(pallas_task, jnp.ones((1, 16, 2, 16)))
+
+
+def test_deploy_untraceable_task_ships_generic():
+    """Only tracing errors (a host conversion of a tracer) mean 'not JAX
+    code' and route the task to the generic worker."""
+    d = Deployment().deploy(host_only_task, jnp.ones(3))
+    assert d.bridge.kind == "generic_worker"
+    blob, _ = d.bridge.entry(d.bridge.pack((jnp.ones(3),), {}, {}))
+    assert d.bridge.unpack_result(blob) == 3.0
+
+
 def test_manifest_persists(tmp_path):
     mpath = str(tmp_path / "manifest.json")
     dep = Deployment(manifest_path=mpath)

@@ -96,6 +96,15 @@ def test_flash_attention_fully_masked_rows_finite():
         assert bool(jnp.all(jnp.isfinite(out))), impl
 
 
+@pytest.mark.parametrize("impl", ["pallas", "pallas_interpret"])
+def test_flash_attention_pallas_refuses_kv_len(impl):
+    """The Pallas prefill kernel has no kv_len bound: asking for one raises
+    instead of quietly running the oracle under the kernel's name."""
+    q = jnp.zeros((1, 16, 2, 16), jnp.float32)
+    with pytest.raises(ValueError, match="kv_len"):
+        attention(q, q, q, kv_len=jnp.asarray([9], jnp.int32), impl=impl)
+
+
 def test_decode_attention_kv_start_matches_unpadded():
     """Decode over a cache with left-pad junk below kv_start must equal
     decode over the compacted cache, ref and Pallas."""

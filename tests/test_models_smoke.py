@@ -139,3 +139,30 @@ def test_param_counts_plausible():
     for arch, (lo, hi) in expect.items():
         n = get_config(arch).param_count()
         assert lo < n < hi, (arch, n)
+
+
+def test_attention_init_scales_by_contracted_width():
+    """q/k/v/o weights scale by the width they contract over (d_model in,
+    Hq·D out).  Taking the heads axis as fan-in made scores ~8x too wide:
+    near-hard attention that turns a rounding difference into another
+    token a few layers later."""
+    from repro.models.attention import attn_init
+    d, hq, hkv, hd = 960, 15, 5, 64
+    p, _ = attn_init(KEY, d, hq, hkv, hd, jnp.float32)
+    tn_std = 0.8796          # std of a standard normal cut to [-2, 2]
+    for name, fan_in in (("wq", d), ("wk", d), ("wv", d), ("wo", hq * hd)):
+        std = float(jnp.std(p[name])) * fan_in ** 0.5 / tn_std
+        assert abs(std - 1.0) < 0.05, (name, std)
+
+
+def test_logits_come_from_the_f32_accumulator():
+    """With bf16 params the fp32 logits are the matmul's f32 accumulator,
+    not bf16 values cast up (those tie near-equal greedy candidates)."""
+    cfg = get_smoke("smollm-360m")
+    assert cfg.param_dtype == "bfloat16" and cfg.logits_fp32
+    model = build_model(cfg)
+    params, _ = model.init(KEY)
+    logits, _ = jax.jit(model.forward)(params, _batch(cfg))
+    assert logits.dtype == jnp.float32
+    as_bf16 = logits.astype(jnp.bfloat16).astype(jnp.float32)
+    assert not bool(jnp.all(as_bf16 == logits))

@@ -9,7 +9,8 @@ import jax.numpy as jnp
 import pytest
 
 from repro.cloud import Session
-from repro.core import Deployment, freeze_function, thaw_function
+from repro.core import (Deployment, RemoteFunction, freeze_function,
+                        thaw_function)
 from repro.core.codeship import CodeShipError
 from repro.dispatch import HttpBackend, WorkerCrash
 from repro.runtime.sandbox import FaultPlan, SandboxHost
@@ -138,8 +139,9 @@ def manifest_deployment(tmp_path):
     return path, Deployment(manifest_path=path)
 
 
-def _pack_invoke(dep, fn, *args, name=None):
-    deployed = dep.deploy(fn, *args)
+def _pack_invoke(dep, fn, *args, jax_traceable=True):
+    deployed = dep.deploy(RemoteFunction(fn, jax_traceable=jax_traceable),
+                          *args)
     payload = deployed.bridge.pack(args, {}, {})
     return deployed, wire.encode_invoke(deployed.name, payload, task_id=1)
 
@@ -159,7 +161,7 @@ def test_worker_host_rebuilds_bridge_from_manifest(manifest_deployment):
 
 def test_worker_host_user_error_keeps_traceback(manifest_deployment):
     path, dep = manifest_deployment
-    _, frame = _pack_invoke(dep, task_raise, 2)
+    _, frame = _pack_invoke(dep, task_raise, 2, jax_traceable=False)
     msg = wire.decode(WorkerHost(path).handle(frame))
     assert isinstance(msg, wire.ErrorReply) and not msg.retryable
     assert msg.etype == "ValueError" and "bad input 2" in msg.message
