@@ -1,20 +1,23 @@
 """Serve smollm-360m at its published widths on one TPU chip and check it.
 
-  python chip_smoke.py [--seed 0] [--requests 8] [--max-new 16]
+  python chip_smoke.py [--seed 0]
 
-Random weights from ``--seed``.  Everything runs in this one process on the
-``threads`` backend: the process holds the chip, so no worker process could
-get it.  Phases:
+Random weights and prompts from ``--seed``.  Everything runs in this one
+process on the ``threads`` backend: the process holds the chip, so no
+worker process could get it.  Phases:
 
-1. kernels vs ref: prefill logits and ``DECODE_STEPS`` decode steps of the
-   Pallas build match an ``attn_impl="ref"`` build of the same weights
-   within ``LOGIT_RTOL``.
-2. serving: ``--requests`` prompts of 64-256 tokens go through the
+1. kernels vs ref: on the contiguous path (flash prefill, decode kernel)
+   and the paged path (chunk prefill, paged decode kernel), prefill logits
+   and ``DECODE_STEPS`` decode steps of the Pallas build match an
+   ``attn_impl="ref"`` build of the same weights within ``LOGIT_RTOL``.
+2. serving: ``N_REQUESTS`` prompts of 64-256 tokens go through the
    iteration-level ``ContinuousBatcher`` (``run_continuous``), once on the
-   slot arena and once on the paged arena (block size 16).  Each
-   completion must equal the same prompt served alone through the same
-   arena — the batch-invariance contract.  Agreement with the solo wave
-   path (``LMServer.serve_wave``) is reported too.
+   slot arena and once on the paged arena (block size ``BLOCK_SIZE``).
+   Each completion must equal the same prompt served alone through the
+   same arena — the batch-invariance contract.  A staggered pass (at most
+   ``STAGGER`` rows in flight) must do the same on the paged arena; on the
+   slot arena it is reported, as is agreement with the solo wave path
+   (``LMServer.serve_wave``).
 3. deployment: every deployed AOT entry point compiled (``aot_xla``, no
    fallback to the eager worker), and its program and the decode step of
    each arena call the attention kernels (``tpu_custom_call``).
@@ -37,6 +40,10 @@ import numpy as np
 
 ROOT = Path(__file__).resolve().parent
 ARCH = "smollm-360m"
+N_REQUESTS = 8
+MAX_NEW = 16
+BLOCK_SIZE = 16
+STAGGER = 3             # rows in flight in the staggered-arrival pass
 DECODE_STEPS = 4
 # Relative L2 error per logits row allowed between the Pallas and the ref
 # build.  Both round every activation to bf16 alike except the attention
@@ -54,41 +61,86 @@ def _rel_l2(a, b):
     return np.linalg.norm(a - b, axis=-1) / np.linalg.norm(b, axis=-1)
 
 
-def kernels_vs_ref(cfg, params, rng):
-    """Prefill + teacher-forced decode under both attention impls."""
+def contiguous_logits(cfg, params, impl, prompts, feed):
+    """Left-padded batch prefill, then teacher-forced decode."""
     import jax
     from repro.models import build_model
     from repro.models.api import grow_cache
     from repro.runtime.server import pack_prompts
 
-    prompts = [list(rng.integers(1, cfg.vocab_size, n)) for n in (200, 77)]
     tokens, lengths = pack_prompts(prompts, pad=cfg.pad_id)
+    model = build_model(cfg.replace(attn_impl=impl))
+    prefill, decode = jax.jit(model.prefill), jax.jit(model.decode)
+    logits, cache = prefill(params, {"tokens": tokens, "lengths": lengths})
+    cache = grow_cache(cfg, cache, tokens.shape[1] + DECODE_STEPS)
+    steps = [logits]
+    for tok in feed:
+        logits, cache = decode(params, cache, tok.astype(np.int32))
+        steps.append(logits)
+    return np.stack([np.asarray(s, np.float32) for s in steps])
+
+
+def paged_logits(cfg, params, impl, prompts, feed):
+    """The paged arena's model calls: each prompt prefilled as one chunk
+    into its own blocks of a shared pool, then teacher-forced decode
+    through the block tables."""
+    import jax
+    from repro.models import transformer
+    from repro.models.api import paged_init_pool
+
+    width = 1 << (max(map(len, prompts)) + DECODE_STEPS - 1).bit_length()
+    n_tab = width // BLOCK_SIZE
+    table = 1 + np.arange(len(prompts) * n_tab,       # block 0 is trash
+                          dtype=np.int32).reshape(len(prompts), n_tab)
+    pool = paged_init_pool(cfg, 1 + table.size, BLOCK_SIZE)
+    pk, pv = pool["k"], pool["v"]
+    chunk = jax.jit(lambda p, t, pk, pv, tab, m, n:
+                    transformer.lm_prefill_paged_chunk(
+                        p, cfg, t, pk, pv, tab, m, n, attn_impl=impl))
+    decode = jax.jit(lambda p, pk, pv, tab, lens, live, t:
+                     transformer.lm_decode_paged(
+                         p, cfg, pk, pv, tab, lens, live, t,
+                         attn_impl=impl))
+    first = []
+    for b, prompt in enumerate(prompts):
+        toks = np.full((1, width), cfg.pad_id, np.int32)
+        toks[0, :len(prompt)] = prompt
+        logits, pk, pv = chunk(params, toks, pk, pv, table[b:b + 1],
+                               np.int32(0), np.int32(len(prompt)))
+        first.append(logits[0])
+    steps = [jax.numpy.stack(first)]
+    lens = np.array([len(p) for p in prompts], np.int32)
+    live = np.ones(len(prompts), bool)
+    for tok in feed:
+        logits, pk, pv = decode(params, pk, pv, table, lens, live,
+                                tok.astype(np.int32))
+        steps.append(logits)
+        lens = lens + 1
+    return np.stack([np.asarray(s, np.float32) for s in steps])
+
+
+def kernels_vs_ref(cfg, params, rng):
+    """Both arenas' model paths, Pallas against an ``attn_impl="ref"``
+    build: prefill logits and teacher-forced decode steps."""
+    prompts = [list(rng.integers(1, cfg.vocab_size, n)) for n in (200, 77)]
     feed = rng.integers(1, cfg.vocab_size, (DECODE_STEPS, len(prompts), 1))
-    out = {}
-    for impl in ("pallas", "ref"):
-        model = build_model(cfg.replace(attn_impl=impl))
-        prefill, decode = jax.jit(model.prefill), jax.jit(model.decode)
-        logits, cache = prefill(params, {"tokens": tokens,
-                                         "lengths": lengths})
-        cache = grow_cache(cfg, cache, tokens.shape[1] + DECODE_STEPS)
-        steps = [logits]
-        for tok in feed:
-            logits, cache = decode(params, cache, tok.astype(np.int32))
-            steps.append(logits)
-        out[impl] = np.stack([np.asarray(s, np.float32) for s in steps])
-    pal, ref = out["pallas"], out["ref"]
-    rel = _rel_l2(pal, ref)
-    agree = int((pal.argmax(-1) == ref.argmax(-1)).sum())
-    print(f"kernels_vs_ref: logits {pal.shape} max_rel_l2 {rel.max():.3e} "
-          f"(bound {LOGIT_RTOL:.3e}) argmax_agree {agree}/{rel.size}")
     fails = []
-    if pal.shape != (DECODE_STEPS + 1, len(prompts), cfg.vocab_size):
-        fails.append(f"kernels_vs_ref: logits shape {pal.shape}")
-    if not (np.isfinite(pal).all() and np.isfinite(ref).all()):
-        fails.append("kernels_vs_ref: non-finite logits")
-    if not rel.max() <= LOGIT_RTOL:
-        fails.append(f"kernels_vs_ref: max relative L2 {rel.max():.3e} > "
-                     f"{LOGIT_RTOL:.3e}")
+    for name, run in (("contiguous", contiguous_logits),
+                      ("paged", paged_logits)):
+        pal, ref = (run(cfg, params, impl, prompts, feed)
+                    for impl in ("pallas", "ref"))
+        rel = _rel_l2(pal, ref)
+        agree = int((pal.argmax(-1) == ref.argmax(-1)).sum())
+        print(f"kernels_vs_ref {name}: logits {pal.shape} max_rel_l2 "
+              f"{rel.max():.3e} (bound {LOGIT_RTOL:.3e}) "
+              f"argmax_agree {agree}/{rel.size}")
+        if pal.shape != (DECODE_STEPS + 1, len(prompts), cfg.vocab_size):
+            fails.append(f"kernels_vs_ref {name}: logits shape {pal.shape}")
+        if not (np.isfinite(pal).all() and np.isfinite(ref).all()):
+            fails.append(f"kernels_vs_ref {name}: non-finite logits")
+        if not rel.max() <= LOGIT_RTOL:
+            fails.append(f"kernels_vs_ref {name}: max relative L2 "
+                         f"{rel.max():.3e} > {LOGIT_RTOL:.3e}")
     return fails
 
 
@@ -114,7 +166,7 @@ def serve(cfg, params, reqs):
 
     fails = []
     arena = dict(max_batch=8, slots=1, iteration_level=True, quantum=8,
-                 prompt_cap=256, block_size=16)
+                 prompt_cap=256, block_size=BLOCK_SIZE)
     with Session("threads") as sess:
         server = LMServer(cfg, params, session=sess,
                           max_new=max(r.max_new for r in reqs))
@@ -139,6 +191,19 @@ def serve(cfg, params, reqs):
                     fails.append(f"{name}: a request was served no tokens")
                 fails += compare(f"{name}_vs_solo", got, solo)
                 compare(f"{name}_vs_solo_wave", got, wave)
+                # Staggered arrivals: at most STAGGER rows in flight, so
+                # later rows are admitted at other cursors than their solo
+                # runs.  The paged arena is invariant by construction; the
+                # slot arena is not on the chip (ROADMAP 2.15), so its
+                # agreement is only reported.
+                t0 = time.perf_counter()
+                late = run_continuous(server, reqs, concurrency=STAGGER,
+                                      paged=paged, **arena)
+                print(f"staggered_{name}_s {time.perf_counter() - t0:.3f}")
+                late_fails = compare(f"{name}_staggered_vs_solo",
+                                     [c.tokens for c in late], solo)
+                if paged:
+                    fails += late_fails
             fails += check_deployment(sess.deployment)
             fails += check_arena_decode(server, params, arena)
         finally:
@@ -203,8 +268,6 @@ def check_deployment(dep):
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--requests", type=int, default=8)
-    ap.add_argument("--max-new", type=int, default=16)
     args = ap.parse_args(argv)
 
     import jax
@@ -256,10 +319,10 @@ def main(argv=None) -> int:
     rng = np.random.default_rng(args.seed)
     reqs = [Request(prompt=[int(t) for t in
                             rng.integers(1, cfg.vocab_size, n)],
-                    max_new=args.max_new)
-            for n in rng.integers(64, 257, args.requests)]
+                    max_new=MAX_NEW)
+            for n in rng.integers(64, 257, N_REQUESTS)]
     print(f"prompt_lengths {[len(r.prompt) for r in reqs]} "
-          f"max_new {args.max_new}")
+          f"max_new {MAX_NEW}")
 
     failures = []
     for name, phase in (("kernels_vs_ref",

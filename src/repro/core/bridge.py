@@ -138,7 +138,12 @@ def make_executor_aot(rf: RemoteFunction, args: tuple, kwargs: dict,
         fn = rebind(base_fn, dyn_) if dyn_ else base_fn
         return fn(*args_, **kwargs_)
 
-    lowered = jax.jit(with_payload).lower(args, kwargs, dynamic)
+    try:
+        lowered = jax.jit(with_payload).lower(args, kwargs, dynamic)
+    except TypeError as e:      # e.g. a str return value: not JAX code
+        e.add_note(f"deploying {rf.human_name!r} as JAX code failed; a task "
+                   f"that is not JAX code is bound with jax_traceable=False")
+        raise
     compiled = lowered.compile()
     dyn_keys = tuple(dynamic)
 

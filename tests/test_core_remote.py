@@ -161,6 +161,22 @@ def test_deploy_surfaces_lowering_errors():
         Deployment().deploy(pallas_task, jnp.ones((1, 16, 2, 16)))
 
 
+def str_task(x):
+    return "done"
+
+
+def test_deploy_non_jax_result_needs_jax_traceable_false():
+    """A default (traceable) task whose result JAX cannot hold fails at
+    deploy and names the switch that ships it to the generic worker."""
+    with pytest.raises(TypeError, match="not a valid JAX type") as info:
+        Deployment().deploy(str_task, jnp.ones(3))
+    assert any("'str_task'" in n and "jax_traceable=False" in n
+               for n in info.value.__notes__)
+    d = Deployment().deploy(RemoteFunction(str_task, jax_traceable=False),
+                            jnp.ones(3))
+    assert d.bridge.kind == "generic_worker"
+
+
 def test_deploy_untraceable_task_ships_generic():
     """Only tracing errors (a host conversion of a tracer) mean 'not JAX
     code' and route the task to the generic worker."""
