@@ -1,0 +1,7 @@
+import os
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+sys.path[:0] = [str(BENCH), str(BENCH.parent / "src")]
