@@ -49,15 +49,8 @@ _M_REQS = obs_metrics.REGISTRY.counter(
     "client_requests_total", "invocations sent over a real transport")
 _M_CRASH = obs_metrics.REGISTRY.counter(
     "client_worker_crashes_total", "transport-level worker losses")
-_M_RTT = obs_metrics.REGISTRY.histogram(
-    "client_roundtrip_ms", "measured client-observed round-trip (ms)")
 _M_QDEPTH = obs_metrics.REGISTRY.gauge(
     "client_queue_depth", "invocations waiting for a dispatch thread")
-_M_CHAOS = obs_metrics.REGISTRY.counter(
-    "chaos_injections_total", "chaos events executed against real workers")
-_M_RESPAWN = obs_metrics.REGISTRY.counter(
-    "client_worker_respawns_total",
-    "worker slots respawned after a transport-level loss")
 
 
 def _deliver(inv: Invocation, ok: bool, value: Any,
@@ -390,7 +383,6 @@ class _TransportBackend:
             if respawn:
                 # a slot burned by a crash (or a chaos kill) coming back:
                 # worker death was added latency, and here is the receipt
-                _M_RESPAWN.inc(backend=type(self).__name__)
                 if self.chaos is not None:
                     self.chaos.record("worker.respawned", slot=idx)
         return slot
@@ -461,7 +453,6 @@ class _TransportBackend:
             return
         rec.modeled_latency_ms = measured_ms
         rec.latency_measured = True
-        _M_RTT.observe(measured_ms, backend=label)
         tspan.set("bytes_out", len(request))
         tspan.set("bytes_in", len(reply))
         tspan.finish()
@@ -553,7 +544,6 @@ class _TransportBackend:
         CONTROL ``chaos`` verb, then lets the invocation proceed into the
         state-lost KeyError."""
         for ev in self.chaos.on_invoke(idx):
-            _M_CHAOS.inc(kind=ev.kind, backend=type(self).__name__)
             if ev.kind == "kill":
                 self.chaos.record("worker.killed", slot=idx)
                 self._chaos_kill(idx, slot)

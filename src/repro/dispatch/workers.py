@@ -173,13 +173,14 @@ class WorkerPool:
         # the in-process analogue of the worker-side entry span: same name
         # ("worker.entry"), same parent (the request's submit span), so a
         # trace looks the same whether the entry ran in a thread or a child
-        # process
+        # process.  It reaches a capturing JAX profiler for every
+        # invocation, sampled or not, and is the current context of the
+        # spans the entry opens.
         from ..obs import trace as obs_trace
-        espan = (obs_trace.TRACER.span("worker.entry", inv.trace,
-                                       function=bridge.name)
-                 if inv.trace is not None else obs_trace.NOOP)
+        espan = obs_trace.TRACER.span("worker.entry", inv.trace,
+                                      profile=True, function=bridge.name)
         try:
-            done = self.sandboxes.invoke(
+            done = obs_trace.bound(espan.ctx, self.sandboxes.invoke)(
                 bridge.entry, bridge.name, inv.payload,
                 task_id=inv.task_id, attempt=inv.attempt)
             fill_record(rec, stats=done.stats, server_s=done.server_s,

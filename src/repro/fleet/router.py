@@ -43,7 +43,6 @@ from dataclasses import dataclass, field
 from typing import Sequence
 
 from ..dispatch.retry import CircuitBreaker
-from ..obs import metrics as obs_metrics
 from ..obs import trace as obs_trace
 from ..runtime.engine import prefix_key
 from ..runtime.server import Completion, LMServer, Request
@@ -51,15 +50,6 @@ from ..serving.aio import await_invocation
 from ..serving.batcher import BatcherStats, EngineLoop
 
 __all__ = ["FleetMember", "FleetRouter", "FleetStats", "run_fleet"]
-
-# registry mirrors of the FleetStats fields — same numbers, uniform
-# names/labels next to the client transport and engine-loop metrics
-_M_ROUTED = obs_metrics.REGISTRY.counter(
-    "fleet_routed_total", "requests placed on a fleet member")
-_M_SCALE = obs_metrics.REGISTRY.counter(
-    "fleet_scale_events_total", "elastic grow/drain decisions")
-_M_HANDOFF = obs_metrics.REGISTRY.counter(
-    "fleet_handoffs_total", "prefill→decode migration groups")
 
 
 @dataclass
@@ -361,7 +351,6 @@ class FleetRouter:
             "member": member.index, "role": member.role, "reason": reason,
             "active": len(self.active_members),
             "queued": self.backlog})
-        _M_SCALE.inc(action=action, role=member.role)
         self._event_span(f"fleet.{action}", member=member.index,
                          role=member.role, reason=reason)
 
@@ -515,7 +504,6 @@ class FleetRouter:
         member, how = self._choose(request.prompt, targets)
         setattr(self.stats, f"routed_{how}",
                 getattr(self.stats, f"routed_{how}") + 1)
-        _M_ROUTED.inc(how=how, role=member.role)
         member.loop.queue.append((request, fut))
         self._arrived.set()
         return member
@@ -525,7 +513,9 @@ class FleetRouter:
         if self._closed:
             raise RuntimeError("fleet router is closed")
         self.start()
-        fut = asyncio.get_running_loop().create_future()
+        loop = asyncio.get_running_loop()
+        fut = loop.create_future()
+        fut._t_submit = loop.time()
         self.route(request, fut)
         return await fut
 
@@ -546,7 +536,6 @@ class FleetRouter:
         member = min(decs, key=lambda m: (m.loop.load, m.index))
         member.loop.intake.extend(items)
         self.stats.handoffs += 1
-        _M_HANDOFF.inc()
         self._event_span("fleet.handoff", rows=len(items),
                          to_member=member.index)
         self._arrived.set()

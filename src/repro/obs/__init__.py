@@ -18,9 +18,12 @@ invocation observable:
 
 The tracing hot path honors a hard off-switch: with tracing disabled
 (the default — ``sample=0``), every instrumentation site is one
-attribute load and a falsy check, and the tracer's ``calls`` counter
-stays at zero (guarded by ``tests/test_obs.py``).  Metrics are always on
-— they are the same counters the sandbox host always kept, just uniform.
+attribute load and a falsy check, plus one ``is_enabled()`` call at the
+sites that also feed a capturing JAX profiler session (the engine loop's
+phases, ``worker.entry``, ``engine.sync``, ``py.gc``), and the tracer's
+``calls`` counter stays at zero (guarded by ``tests/test_obs.py``).
+Metrics are always on — they are the same counters the sandbox host
+always kept, just uniform.
 """
 from __future__ import annotations
 

@@ -28,9 +28,6 @@ from typing import Iterable, Mapping
 # hops up to multi-second cold compiles
 DEFAULT_BUCKETS_MS = (1.0, 2.0, 5.0, 10.0, 20.0, 50.0, 100.0, 200.0,
                       500.0, 1000.0, 2000.0, 5000.0, 10000.0)
-# seconds-flavored twin for busy-time style histograms
-DEFAULT_BUCKETS_S = (0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 10.0,
-                     60.0)
 
 
 def _label_key(labels: Mapping[str, object]) -> str:

@@ -15,6 +15,13 @@ in ``tests/test_obs.py`` pins this.  Sampled-out traces cost one sampler
 roll at the root and nothing per child (children of an unsampled root get
 the no-op handle).
 
+A second sink, the JAX profiler: a site opened with ``profile=True`` also
+records as a ``jax.profiler.TraceAnnotation`` whenever a profiler session
+is capturing, sampled or not, so the span lands on the device trace's
+clock beside the device operations.  Off, that costs one ``is_enabled()`` call per site.
+JAX is never imported here: the sink looks for ``jax.profiler`` among the
+loaded modules, so ``repro.obs`` stays importable without JAX.
+
 Export is Chrome-trace JSON (:func:`export_chrome` / :func:`dump_trace`):
 load the file in ``chrome://tracing`` or Perfetto.  Span linkage
 (``trace_id`` / ``span_id`` / ``parent_span_id``) rides in each event's
@@ -22,9 +29,11 @@ load the file in ``chrome://tracing`` or Perfetto.  Span linkage
 """
 from __future__ import annotations
 
+import gc
 import json
 import os
 import random
+import sys
 import threading
 import time
 from collections import deque
@@ -140,32 +149,109 @@ class _NoopHandle:
 NOOP = _NoopHandle()
 
 
+# ------------------------------------------------------- profiler sink --
+
+_ANNOTATION = None      # jax.profiler.TraceAnnotation, once JAX is loaded
+
+
+def _capturing():
+    """The ``TraceAnnotation`` class while a JAX profiler session is
+    capturing, else None.  Only looks JAX up among the loaded modules (a
+    module still importing has no ``TraceAnnotation`` yet)."""
+    global _ANNOTATION
+    ann = _ANNOTATION
+    if ann is None:
+        ann = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation",
+                      None)
+        if ann is None:
+            return None
+        _ANNOTATION = ann
+    return ann if ann.is_enabled() else None
+
+
+def _open(annotation, name: str, attrs: dict):
+    if annotation is None:
+        return None
+    ann = annotation(name, **attrs)
+    ann.__enter__()
+    return ann
+
+
+def _close(ann, status: str) -> None:
+    if ann is not None:
+        if status != "ok":
+            ann.set_metadata(status=status)
+        ann.__exit__(None, None, None)
+
+
+class _ProfiledHandle:
+    """A span that reaches the profiler only (tracing off, no sampled
+    parent, or ``py.gc``): falsy like NOOP, so nothing parents under it in
+    the ring.  Finish it on the thread that opened it."""
+    __slots__ = ("_ann",)
+    ctx = None
+
+    def __init__(self, annotation, name: str, attrs: dict):
+        self._ann = _open(annotation, name, attrs)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, etype, err, tb) -> None:
+        if etype is not None:
+            self.set("error.type", etype.__name__)
+        self.finish("ok" if etype is None else "error")
+
+    def set(self, key: str, value) -> None:
+        if self._ann is not None:
+            self._ann.set_metadata(**{key: value})
+
+    def finish(self, status: str = "ok") -> None:
+        ann, self._ann = self._ann, None
+        _close(ann, status)
+
+    def __bool__(self) -> bool:
+        return False
+
+
+def _profiled(name: str, **attrs):
+    """A span for the profiler sink alone: a ``TraceAnnotation`` while a
+    profiler session is capturing, else :data:`NOOP`."""
+    ann = _capturing()
+    return NOOP if ann is None else _ProfiledHandle(ann, name, attrs)
+
+
 class _SpanHandle:
     """A live span: context manager or manually ``finish()``-ed (exactly
     once).  ``set`` adds attributes; an exception leaving the ``with``
     marks status=error and records the exception type/message."""
 
     __slots__ = ("_sink", "name", "ctx", "parent_id", "_t0_perf", "attrs",
-                 "_proc", "_done")
+                 "_proc", "_done", "_ann")
 
     def __init__(self, sink, name: str, ctx: SpanContext,
-                 parent_id: str | None, proc: str, attrs: dict):
+                 parent_id: str | None, proc: str, attrs: dict,
+                 annotation=None):
         self._sink = sink
         self.name = name
         self.ctx = ctx
         self.parent_id = parent_id
         self.attrs = attrs
         self._proc = proc
+        self._ann = _open(annotation, name, attrs)
         self._t0_perf = time.perf_counter()
         self._done = False
 
     def set(self, key: str, value) -> None:
         self.attrs[key] = value
+        if self._ann is not None:
+            self._ann.set_metadata(**{key: value})
 
     def finish(self, status: str = "ok") -> None:
         if self._done:
             return
         self._done = True
+        _close(self._ann, status)
         self._sink(Span(
             name=self.name, trace_id=self.ctx.trace_id,
             span_id=self.ctx.span_id, parent_id=self.parent_id,
@@ -259,19 +345,23 @@ class Tracer:
         ctx = SpanContext(_new_id(64), _new_id(64), time.time())
         return _SpanHandle(self._record, name, ctx, None, self.proc, attrs)
 
-    def span(self, name: str, parent: SpanContext | None = None, **attrs):
+    def span(self, name: str, parent: SpanContext | None = None, *,
+             profile: bool = False, **attrs):
         """A child span under ``parent`` (or the thread's current context).
-        No parent → no span: orphan spans cannot stitch into any tree."""
+        No parent → no ring span: orphan spans cannot stitch into any tree.
+        ``profile=True`` also records it in a capturing JAX profiler
+        session, whether or not the ring records it."""
         if not self.enabled:
-            return NOOP
+            return _profiled(name, **attrs) if profile else NOOP
         if parent is None:
             parent = self.current()
             if parent is None:
-                return NOOP
+                return _profiled(name, **attrs) if profile else NOOP
         self.calls += 1
         ctx = SpanContext(parent.trace_id, _new_id(64), time.time())
         return _SpanHandle(self._record, name, ctx, parent.span_id,
-                           self.proc, attrs)
+                           self.proc, attrs,
+                           _capturing() if profile else None)
 
     def span_at(self, name: str, parent: SpanContext, t_start: float,
                 dur_s: float, status: str = "ok", **attrs) -> None:
@@ -402,6 +492,29 @@ def current() -> SpanContext | None:
 
 def dump_trace(path: str) -> int:
     return TRACER.dump(path)
+
+
+# ------------------------------------------------------------ gc pauses --
+
+_gc_span = NOOP
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: each collection becomes a ``py.gc`` span on
+    the thread it interrupted (generation and objects collected as
+    attributes).  It reaches the profiler sink alone and takes no Python
+    lock: a collection can interrupt a thread that holds the tracer's own
+    locks.  Returns at once when no profiler session is capturing."""
+    global _gc_span
+    if phase == "start":
+        _gc_span = _profiled("py.gc", generation=info["generation"])
+    elif _gc_span is not NOOP:
+        sp, _gc_span = _gc_span, NOOP
+        sp.set("collected", info["collected"])
+        sp.finish()
+
+
+gc.callbacks.append(_on_gc)           # once per process: at first import
 
 
 class bound:

@@ -46,6 +46,7 @@ from ..models.api import (PagedArena, SEQ_CACHE_KEYS, _attn_impl,
                           cache_extract_rows, cache_free_rows,
                           cache_insert_rows, cache_insert_rows_masked,
                           cache_shift_left, paged_init_pool, paged_supported)
+from ..obs.trace import TRACER
 from ..serialization import decode_binary, encode_binary
 from . import state
 from .radix import RadixIndex
@@ -72,6 +73,12 @@ def prefix_key(tokens: Sequence[int]) -> str:
     h.update(len(tokens).to_bytes(8, "little"))
     h.update(np.asarray(tokens, np.int64).tobytes())
     return h.hexdigest()
+
+
+def _sync(what: str):
+    """``engine.sync``: a span around a device-to-host read (the entry
+    waits there for the device to finish its programs)."""
+    return TRACER.span("engine.sync", profile=True, what=what)
 
 
 def is_state_lost(err: BaseException) -> bool:
@@ -169,8 +176,11 @@ def engine_prefill(params, tokens, lengths, *, cfg, handle, batch, cap,
     a row's fresh cache in the arena's prefix store; ``hit_slots`` /
     ``hit_keys`` are rows served straight from it; ``evict_keys`` applies
     the client's LRU decisions.  Returns ``{"first": first token per
-    inserted row (miss order then hit order), "idx": cursor}`` — the
-    cache itself stays resident and is never serialized back.
+    inserted row (miss order then hit order), "idx": cursor}`` and the
+    call's prompt tokens: prefilled (``prefill_tokens``, and
+    ``padded_tokens`` as the program ran them) and served from the store
+    (``prefix_tokens``).  The cache itself stays resident and is never
+    serialized back.
     """
     def make():
         return {"cache": arena_init_cache(cfg, batch, cap, cursor0),
@@ -192,19 +202,23 @@ def engine_prefill(params, tokens, lengths, *, cfg, handle, batch, cap,
             a["prefix_tokens"] -= ent[1]
 
     first_out: list[int] = []
+    real = padded = adopted = 0
     if len(miss_slots):
         n = len(miss_slots)
-        if int(cache["idx"]) < int(tokens.shape[1]) \
-                and cfg.family != "ssm":
+        with _sync("idx"):
+            idx = int(cache["idx"])
+        if idx < int(tokens.shape[1]) and cfg.family != "ssm":
             raise ValueError(
                 f"prefill width {int(tokens.shape[1])} exceeds arena "
-                f"cursor {int(cache['idx'])}")
+                f"cursor {idx}")
         tokens = jnp.asarray(tokens)
         lengths = np.asarray(lengths, np.int32)
         width = int(tokens.shape[1])
+        real, padded = int(lengths[:n].sum()), int(tokens.shape[0]) * width
         first, pcache = _prefill_fn(cfg)(params, tokens,
                                          jnp.asarray(lengths))
-        first = np.asarray(first)
+        with _sync("first"):
+            first = np.asarray(first)
         for j, key in enumerate(store_keys):
             if key is None or key in a["prefix"]:
                 continue
@@ -234,14 +248,18 @@ def engine_prefill(params, tokens, lengths, *, cfg, handle, batch, cap,
                 f"prefix key {key[:12]}… not resident for state handle "
                 f"{handle!r} (stale client mirror)")
         row, length, t0, width = ent
+        adopted += length
         cache, last = _insert_one_fn(cfg, width)(
             cache, last, row, jnp.asarray([slot], jnp.int32),
             jnp.asarray([length], jnp.int32), t0)
         first_out.append(t0)
 
     a["cache"], a["last"] = cache, last
-    return {"first": np.asarray(first_out, np.int32),
-            "idx": int(cache["idx"])}
+    with _sync("idx"):
+        idx = int(cache["idx"])
+    return {"first": np.asarray(first_out, np.int32), "idx": idx,
+            "prefill_tokens": real, "padded_tokens": padded,
+            "prefix_tokens": adopted}
 
 
 def engine_decode(params, *, cfg, handle, k, free_slots=(),
@@ -257,7 +275,9 @@ def engine_decode(params, *, cfg, handle, k, free_slots=(),
         free_mask[np.asarray(free_slots, np.int64)] = True
     if cfg.family != "ssm":
         cap = a["cap"]
-        if int(cache["idx"]) + k >= cap:
+        with _sync("idx"):
+            idx = int(cache["idx"])
+        if idx + k >= cap:
             # compaction bound: minimum start over rows that are NOT being
             # freed this call (schedulers pass every non-live slot in
             # free_slots each chunk, so idle freed slots cannot pin the
@@ -277,7 +297,9 @@ def engine_decode(params, *, cfg, handle, k, free_slots=(),
     cache, last, toks = _decode_fn(cfg, k)(params, cache, last[:, None],
                                            jnp.asarray(free_mask))
     a["cache"], a["last"] = cache, last
-    return {"tokens": np.asarray(toks), "idx": int(cache["idx"])}
+    with _sync("tokens"):
+        toks, idx = np.asarray(toks), int(cache["idx"])
+    return {"tokens": toks, "idx": idx}
 
 
 # ------------------------------------------------- paged-arena entry fns --
@@ -409,14 +431,16 @@ def engine_paged_prefill(params, *, cfg, handle, batch, blocks, table_width,
         pa.release(slot)
         pending.pop(slot, None)
 
+    adopted = 0          # prompt tokens this call took from the radix index
     for slot, toks in admit:
         slot = int(slot)
         toks = [int(t) for t in toks]
         matched = _paged_match(pa, radix, slot, toks, 0)
+        adopted += matched
         pending[slot] = {"tokens": toks, "done": matched, "matched": matched}
         order.append(slot)
 
-    spent = 0
+    spent = padded = 0
     out: dict[int, dict] = {}
     while order:
         slot = order[0]
@@ -424,8 +448,9 @@ def engine_paged_prefill(params, *, cfg, handle, batch, blocks, table_width,
         if ent is None:                       # freed mid-prefill
             order.pop(0)
             continue
-        toks, done = ent["tokens"], ent["done"]
-        done = _paged_match(pa, radix, slot, toks, done)
+        toks, done0 = ent["tokens"], ent["done"]
+        done = _paged_match(pa, radix, slot, toks, done0)
+        adopted += done - done0
         need = len(toks) - done
         room = (len(toks) if budget <= 0
                 else budget - spent)
@@ -443,13 +468,15 @@ def engine_paged_prefill(params, *, cfg, handle, batch, blocks, table_width,
         a["pool_k"], a["pool_v"] = pk, pv
         done += c_real
         spent += c_real
+        padded += c_b
         ent["done"] = done
         if done == len(toks):
             order.pop(0)
             pending.pop(slot)
             pa.len[slot] = done
             pa.live[slot] = True
-            t0 = int(np.asarray(first)[0])
+            with _sync("first"):
+                t0 = int(np.asarray(first)[0])
             a["last"][slot] = t0
             nb_full = (done // pa.bs) * pa.bs
             if nb_full and radix_tokens > 0:
@@ -468,7 +495,9 @@ def engine_paged_prefill(params, *, cfg, handle, batch, blocks, table_width,
     a["occupancy"] = occ
     # str slot keys: the wire serializer only carries str-keyed dicts
     return {"slots": {str(s): v for s, v in out.items()},
-            "pending": len(pending), "occupancy": occ}
+            "pending": len(pending), "occupancy": occ,
+            "prefill_tokens": spent, "padded_tokens": padded,
+            "prefix_tokens": adopted}
 
 
 def engine_paged_decode(params, *, cfg, handle, k, free_slots=(),
@@ -490,12 +519,14 @@ def engine_paged_decode(params, *, cfg, handle, k, free_slots=(),
         params, a["pool_k"], a["pool_v"], jnp.asarray(pa.table),
         jnp.asarray(pa.len), jnp.asarray(pa.live), jnp.asarray(a["last"]))
     a["pool_k"], a["pool_v"] = pk, pv
-    a["last"] = np.asarray(last).astype(np.int32)
+    with _sync("tokens"):
+        a["last"] = np.asarray(last).astype(np.int32)
+        toks = np.asarray(toks)
     pa.len[pa.live] += k
     occ = pa.occupancy()
     occ["radix_tokens"] = radix.tokens
     a["occupancy"] = occ
-    return {"tokens": np.asarray(toks), "occupancy": occ}
+    return {"tokens": toks, "occupancy": occ}
 
 
 # ------------------------------------------------------- row migration ------

@@ -207,11 +207,6 @@ class SandboxHost:
             "sandbox_warm_hits_total", "invocations served by a warm sandbox")
         self._m_busy = self.metrics.counter(
             "entry_busy_seconds_total", "wall time inside entry callables")
-        self._m_live = self.metrics.gauge(
-            "sandbox_live_instances", "currently provisioned sandboxes")
-        self._m_entry = self.metrics.histogram(
-            "entry_seconds", "per-invocation entry wall time (s)",
-            buckets=obs_metrics.DEFAULT_BUCKETS_S)
         self._fn_names: set[str] = set()
 
     # ----------------------------------------------------------- lifecycle
@@ -226,7 +221,6 @@ class SandboxHost:
             self._next_worker_id += 1
             self._live_instances += 1
             self._m_cold.inc(function=function_name)
-            self._m_live.set(self._live_instances)
             return WorkerInstance(self._next_worker_id, function_name), True
 
     def release(self, inst: WorkerInstance) -> None:
@@ -237,7 +231,6 @@ class SandboxHost:
         """A crashed sandbox is never reused."""
         with self._lock:
             self._live_instances -= 1
-            self._m_live.set(self._live_instances)
 
     def drain(self, function_name: str | None = None) -> int:
         """Scale-in: drop warm sandboxes (next invocations pay cold starts)."""
@@ -248,7 +241,6 @@ class SandboxHost:
             else:
                 n = len(self._warm.pop(function_name, []))
             self._live_instances -= n
-            self._m_live.set(self._live_instances)
             return n
 
     @property
@@ -319,7 +311,6 @@ class SandboxHost:
             elapsed = time.perf_counter() - t0
             inst.busy_s += elapsed
             self._m_busy.inc(elapsed, function=function_name)
-            self._m_entry.observe(elapsed, function=function_name)
         if straggle:
             if self.fault_plan.straggler_sleep_s:
                 time.sleep(self.fault_plan.straggler_sleep_s)

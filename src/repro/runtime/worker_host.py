@@ -46,15 +46,11 @@ from .sandbox import SandboxHost
 # merged into the host_stats reply and the /metrics exposition)
 _M_REQS = obs_metrics.REGISTRY.counter(
     "worker_requests_total", "INVOKE frames handled")
-_M_CTRL = obs_metrics.REGISTRY.counter(
-    "worker_control_total", "CONTROL frames handled")
 _M_INFLIGHT = obs_metrics.REGISTRY.gauge(
     "worker_inflight", "INVOKE frames currently executing")
 _M_EXPIRED = obs_metrics.REGISTRY.counter(
     "worker_deadline_rejections_total",
     "INVOKE frames rejected because their deadline had already passed")
-_M_CHAOS = obs_metrics.REGISTRY.counter(
-    "chaos_worker_events_total", "chaos CONTROL verbs executed worker-side")
 # eagerly registered so every /metrics exposition carries the serving
 # histograms' bucket layout even before (or without) the batcher running
 # in this process — the client-side batcher observes into the same names,
@@ -149,7 +145,6 @@ class WorkerHost:
         except wire.WireProtocolError as e:
             return wire.encode_error(e, retryable=False)
         if isinstance(msg, wire.ControlRequest):
-            _M_CTRL.inc(op=msg.op)
             return self._handle_control(msg)
         if not isinstance(msg, wire.InvokeRequest):
             return wire.encode_error(
@@ -263,7 +258,6 @@ class WorkerHost:
             # the process directly, e.g. an external url= http worker).
             from . import state
             action = msg.data.get("action")
-            _M_CHAOS.inc(action=str(action))
             if action == "expire_leases":
                 expired = state.expire_all(msg.data.get("handles"))
                 return wire.encode_control("chaos", ok=True, expired=expired)

@@ -38,6 +38,7 @@ from __future__ import annotations
 import asyncio
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Sequence
 
@@ -53,22 +54,17 @@ from .aio import await_invocation
 # eventually fail rather than orbit the fleet forever.
 MAX_ROW_REPLAYS = 3
 
-# serving metrics (process-default registry): the uniform mirrors of the
-# scheduler's BatcherStats, queryable through Session.stats()["metrics"]
-# and merged fleet-wide with the worker-side registries.  TTFT/TPOT are
-# stamped once here (see _LiveRow.token_times_ms) — serve_bench consumes
-# these stamps instead of re-deriving.
+# serving latency histograms (process-default registry), queryable through
+# Session.stats()["metrics"] and merged fleet-wide with the worker-side
+# registries.  TTFT/TPOT are stamped once here (see
+# _LiveRow.token_times_ms) — serve_bench consumes these stamps instead of
+# re-deriving.  TTFT and the queue wait start at ContinuousBatcher.submit.
 _M_TTFT = obs_metrics.REGISTRY.histogram(
     "serve_ttft_ms", "time to first token, client-observed (ms)")
 _M_TPOT = obs_metrics.REGISTRY.histogram(
     "serve_tpot_ms", "mean inter-token time per request (ms)")
-_M_DONE = obs_metrics.REGISTRY.counter(
-    "serve_completions_total", "requests served to completion")
-_M_CHUNKS = obs_metrics.REGISTRY.counter(
-    "serve_decode_chunks_total", "iteration-level decode round-trips")
-_M_RECOVERED = obs_metrics.REGISTRY.counter(
-    "recovery_rows_total",
-    "live rows replayed after worker/state loss instead of failing")
+_M_WAIT = obs_metrics.REGISTRY.histogram(
+    "serve_queue_wait_ms", "submit to admission into an arena row (ms)")
 
 
 @dataclass
@@ -87,6 +83,8 @@ class BatcherStats:
     decode_chunks: int = 0           # decode round-trips
     prefix_hits: int = 0             # rows whose prefill was skipped
     prefix_misses: int = 0
+    prefill_tokens: int = 0          # prompt tokens run through prefill
+    prefix_tokens: int = 0           # prompt tokens served from the index
     wave_fallbacks: int = 0          # requests too big for the arena
     state_resets: int = 0            # arenas rebuilt after state loss
     recovered_rows: int = 0          # live rows replayed instead of failed
@@ -114,6 +112,8 @@ class BatcherStats:
                         "decode_chunks": self.decode_chunks,
                         "prefix_hits": self.prefix_hits,
                         "prefix_misses": self.prefix_misses,
+                        "prefill_tokens": self.prefill_tokens,
+                        "prefix_tokens": self.prefix_tokens,
                         "wave_fallbacks": self.wave_fallbacks,
                         "state_resets": self.state_resets,
                         "recovered_rows": self.recovered_rows,
@@ -129,7 +129,7 @@ class _LiveRow:
     """One occupied arena slot (iteration-level scheduler bookkeeping)."""
     request: Request
     fut: asyncio.Future
-    t_arrival: float
+    t_arrival: float                 # admission (the loop's clock)
     tokens: list = field(default_factory=list)
     ttft_ms: float = 0.0
     cost_gb_s: float = 0.0
@@ -140,6 +140,7 @@ class _LiveRow:
     # replayed onto a fresh arena, and whether it survived at least one
     recovered: bool = False
     replays: int = 0
+    t_submit: float | None = None    # ContinuousBatcher.submit, if known
 
     @property
     def remaining(self) -> int:
@@ -223,6 +224,7 @@ class EngineLoop:
         self.migrated_in = 0
         self.migrated_out = 0
         self._root_span = obs_trace.NOOP       # set for real in run()
+        self._sched = obs_trace.NOOP           # the open engine.schedule
         self._kwargs = dict(rows=max(1, max_batch),
                             prompt_cap=prompt_cap, quantum=quantum,
                             prefix_tokens=prefix_tokens, ttl_s=lease_ttl_s,
@@ -276,8 +278,9 @@ class EngineLoop:
                 recovered=row.recovered))
         self.stats.requests += 1
         self.served += 1
-        _M_DONE.inc()
-        _M_TTFT.observe(row.ttft_ms)
+        wait_ms = 0.0 if row.t_submit is None \
+            else (row.t_arrival - row.t_submit) * 1000.0
+        _M_TTFT.observe(row.ttft_ms + wait_ms)
         if len(times) > 1:
             _M_TPOT.observe((times[-1] - times[0]) / (len(times) - 1))
 
@@ -320,6 +323,7 @@ class EngineLoop:
         fut._replay = {"request": orig,
                        "tokens": [int(t) for t in row.tokens],
                        "t_arrival": row.t_arrival, "ttft_ms": row.ttft_ms,
+                       "t_submit": row.t_submit,
                        "token_times_ms": list(row.token_times_ms),
                        "cost": row.cost_gb_s, "attempts": row.replays + 1}
         replay = Request(
@@ -340,11 +344,12 @@ class EngineLoop:
                        ttft_ms=meta["ttft_ms"],
                        cost_gb_s=meta["cost"] + share,
                        token_times_ms=list(meta["token_times_ms"]) + [t_ms],
-                       recovered=True, replays=meta["attempts"])
+                       recovered=True, replays=meta["attempts"],
+                       t_submit=meta["t_submit"])
         return row
 
     def _lose_state(self, err: BaseException) -> None:
-        recovered = failed = 0
+        recovered = 0
         now = asyncio.get_running_loop().time()
         for rows in (self.live, self.pending):
             for slot, row in rows.items():
@@ -359,25 +364,39 @@ class EngineLoop:
                     recovered += 1
                 else:
                     self._fail(row.fut, err, "engine failed")
-                    failed += 1
             rows.clear()
         self._to_free.clear()      # the new handle starts with a fresh pool
         self.engine.reset()
         self.stats.state_resets += 1
-        if recovered:
-            self.stats.recovered_rows += recovered
-            _M_RECOVERED.inc(recovered)
-            rspan = self._span("engine.recover_rows", rows=recovered,
-                               failed=failed, error=type(err).__name__)
-            rspan.finish()
+        self.stats.recovered_rows += recovered
 
     def _span(self, name: str, **attrs):
-        """A child span under this loop's root trace (NOOP when tracing is
-        off or this loop's root was sampled out)."""
-        root = self._root_span
-        if not root:
-            return obs_trace.NOOP
-        return obs_trace.TRACER.span(name, root.ctx, **attrs)
+        """A span of this loop: a child of its root trace in the ring when
+        that root was sampled, and an annotation in a capturing JAX
+        profiler session either way."""
+        return obs_trace.TRACER.span(name, self._root_span.ctx,
+                                     profile=True, **attrs)
+
+    @contextmanager
+    def _phase(self, name: str, **attrs):
+        """Leave the loop's own work (the open ``engine.schedule`` span)
+        for an engine call or the idle wait, and resume it afterwards: the
+        loop thread is in exactly one of these spans at a time, and none
+        of them encloses a whole pass."""
+        self._sched.finish()
+        try:
+            with self._span(name, **attrs) as sp:
+                yield sp
+        finally:
+            self._sched = self._span("engine.schedule")
+
+    def _admitted(self, fut, now: float) -> float | None:
+        """Submit stamp of a newly admitted request; its queue wait goes to
+        ``serve_queue_wait_ms``."""
+        t = getattr(fut, "_t_submit", None)
+        if t is not None:
+            _M_WAIT.observe((now - t) * 1000.0)
+        return t
 
     def _bound(self, span, fn):
         """Bind ``span`` as the dispatch parent for ``fn`` when it runs on
@@ -418,6 +437,7 @@ class EngineLoop:
             # (chaos straggle, long pack) cannot expire live rows' state
             engine.start_heartbeat()
 
+        self._sched = self._span("engine.schedule")
         try:
             while True:
                 self._prune()
@@ -465,7 +485,8 @@ class EngineLoop:
                     self.arrived.clear()
                     if waiting or self.closing:
                         continue
-                    await self.arrived.wait()
+                    with self._phase("engine.idle"):
+                        await self.arrived.wait()
                     continue
 
                 # -------------------------------------------- decode chunk
@@ -481,21 +502,19 @@ class EngineLoop:
                     # freeze-time value would pin arena compaction forever
                     idle = tuple(s for s in range(engine.rows)
                                  if s not in live)
-                cspan = self._span("engine.decode_quantum", k=k,
-                                   rows=len(live))
                 try:
-                    inv_fut = await loop.run_in_executor(
-                        self.cpu, self._bound(cspan, engine.submit_step),
-                        k, idle)
-                    reply = engine.observe(await await_invocation(inv_fut))
+                    with self._phase("engine.decode_quantum", k=k,
+                                     rows=len(live)) as cspan:
+                        inv_fut = await loop.run_in_executor(
+                            self.cpu, self._bound(cspan, engine.submit_step),
+                            k, idle)
+                        reply = engine.observe(
+                            await await_invocation(inv_fut))
                 except BaseException as e:
-                    cspan.set("error.type", type(e).__name__)
-                    cspan.finish("error")
                     self._lose_state(e)
                     if isinstance(e, asyncio.CancelledError):
                         raise
                     continue
-                cspan.finish()
                 self._to_free.difference_update(idle)
                 self._note_occupancy()
                 toks = reply["tokens"]
@@ -517,10 +536,10 @@ class EngineLoop:
                 self.stats.decode_chunks += 1
                 self.stats.decode_steps += k
                 self.stats.occupancy_sum += len(live)
-                _M_CHUNKS.inc()
                 self.chunks += 1
                 self.chunk_occupancy += len(live)
         finally:
+            self._sched.finish()
             self._root_span.set("served", self.served)
             self._root_span.set("chunks", self.chunks)
             self._root_span.finish()
@@ -553,18 +572,19 @@ class EngineLoop:
             return
         t_sent = loop.time()
         hits0, miss0 = engine.prefix_hits, engine.prefix_misses
-        pspan = self._span("engine.prefill", rows=len(take))
         try:
-            inv_fut, order = await loop.run_in_executor(
-                self.cpu, self._bound(pspan, engine.submit_admit),
-                [(slot, r.prompt) for slot, r, _ in take],
-                # an arena holding live rows must already exist: never
-                # silently recreate an expired lease under them
-                not live)
-            reply = engine.observe(await await_invocation(inv_fut))
+            with self._phase("engine.prefill", rows=len(take)) as pspan:
+                inv_fut, order = await loop.run_in_executor(
+                    self.cpu, self._bound(pspan, engine.submit_admit),
+                    [(slot, r.prompt) for slot, r, _ in take],
+                    # an arena holding live rows must already exist: never
+                    # silently recreate an expired lease under them
+                    not live)
+                reply = engine.observe(await await_invocation(inv_fut))
+                pspan.set("prefix_hits", engine.prefix_hits - hits0)
+                pspan.set("prefix_misses", engine.prefix_misses - miss0)
+                self._note_chunk(pspan, reply)
         except BaseException as e:
-            pspan.set("error.type", type(e).__name__)
-            pspan.finish("error")
             for slot, r, fut in take:
                 free.append(slot)
                 # infrastructure loss during admission: nothing was decoded
@@ -580,9 +600,6 @@ class EngineLoop:
             if isinstance(e, asyncio.CancelledError):
                 raise
             return
-        pspan.set("prefix_hits", engine.prefix_hits - hits0)
-        pspan.set("prefix_misses", engine.prefix_misses - miss0)
-        pspan.finish()
         now = loop.time()
         rec = inv_fut.record
         share = (rec.billed_gb_s / len(take)) if rec else 0.0
@@ -600,7 +617,8 @@ class EngineLoop:
             live[slot] = _LiveRow(request=r, fut=fut, t_arrival=t_sent,
                                   tokens=[int(t0)], ttft_ms=ttft,
                                   cost_gb_s=share,
-                                  token_times_ms=[ttft])
+                                  token_times_ms=[ttft],
+                                  t_submit=self._admitted(fut, t_sent))
         self.stats.admission_groups += 1
         if self.role == "prefill":
             await self._handoff_rows(loop, list(live), is_state_lost)
@@ -641,6 +659,18 @@ class EngineLoop:
         st.shared_blocks_peak = max(st.shared_blocks_peak,
                                     int(occ.get("shared_blocks", 0)))
 
+    def _note_chunk(self, span, reply: dict) -> None:
+        """A prefill call's prompt tokens: run through the model (real, and
+        padded to the programs' widths) and served from the arena's prefix
+        store or radix index."""
+        real = int(reply.get("prefill_tokens", 0))
+        matched = int(reply.get("prefix_tokens", 0))
+        self.stats.prefill_tokens += real
+        self.stats.prefix_tokens += matched
+        span.set("prompt_tokens", real)
+        span.set("padded_tokens", int(reply.get("padded_tokens", 0)))
+        span.set("prefix_tokens", matched)
+
     async def _admit_paged(self, loop, is_state_lost, take) -> None:
         """Paged admission: one prefill round-trip admits the new rows and
         advances them up to the chunk budget.  Rows that finish inside the
@@ -651,17 +681,19 @@ class EngineLoop:
             return
         t_sent = loop.time()
         hits0, miss0 = engine.prefix_hits, engine.prefix_misses
-        pspan = self._span("engine.prefill_chunk", rows=len(take))
         try:
-            inv_fut, _ = await loop.run_in_executor(
-                self.cpu, self._bound(pspan, engine.submit_admit),
-                [(slot, r.prompt) for slot, r, _ in take],
-                not (live or self.pending), tuple(self._to_free))
-            reply = engine.observe_paged_prefill(
-                await await_invocation(inv_fut))
+            with self._phase("engine.prefill_chunk", rows=len(take),
+                             pending=len(self.pending)) as pspan:
+                inv_fut, _ = await loop.run_in_executor(
+                    self.cpu, self._bound(pspan, engine.submit_admit),
+                    [(slot, r.prompt) for slot, r, _ in take],
+                    not (live or self.pending), tuple(self._to_free))
+                reply = engine.observe_paged_prefill(
+                    await await_invocation(inv_fut))
+                pspan.set("radix_hits", engine.prefix_hits - hits0)
+                pspan.set("radix_misses", engine.prefix_misses - miss0)
+                self._note_chunk(pspan, reply)
         except BaseException as e:
-            pspan.set("error.type", type(e).__name__)
-            pspan.finish("error")
             for slot, r, fut in take:
                 free.append(slot)
                 if not fut.done() and self._replayable(e) \
@@ -675,9 +707,6 @@ class EngineLoop:
             if isinstance(e, asyncio.CancelledError):
                 raise
             return
-        pspan.set("radix_hits", engine.prefix_hits - hits0)
-        pspan.set("radix_misses", engine.prefix_misses - miss0)
-        pspan.finish()
         self._to_free.clear()
         now = loop.time()
         rec = inv_fut.record
@@ -695,10 +724,12 @@ class EngineLoop:
                     tokens=list(meta["tokens"]), ttft_ms=meta["ttft_ms"],
                     cost_gb_s=meta["cost"],
                     token_times_ms=list(meta["token_times_ms"]),
-                    recovered=True, replays=meta["attempts"])
+                    recovered=True, replays=meta["attempts"],
+                    t_submit=meta["t_submit"])
                 continue
-            self.pending[slot] = _LiveRow(request=r, fut=fut,
-                                          t_arrival=t_sent)
+            self.pending[slot] = _LiveRow(
+                request=r, fut=fut, t_arrival=t_sent,
+                t_submit=self._admitted(fut, t_sent))
         self._promote(reply, now, share)
         self.stats.admission_groups += 1
         self._note_occupancy()
@@ -709,21 +740,20 @@ class EngineLoop:
         pool's block accounting is mid-flight — so it resets like a failed
         decode chunk."""
         engine = self.engine
-        pspan = self._span("engine.prefill_chunk", pending=len(self.pending))
         try:
-            inv_fut = await loop.run_in_executor(
-                self.cpu, self._bound(pspan, engine.submit_prefill_step),
-                tuple(self._to_free))
-            reply = engine.observe_paged_prefill(
-                await await_invocation(inv_fut))
+            with self._phase("engine.prefill_chunk", rows=0,
+                             pending=len(self.pending)) as pspan:
+                inv_fut = await loop.run_in_executor(
+                    self.cpu, self._bound(pspan, engine.submit_prefill_step),
+                    tuple(self._to_free))
+                reply = engine.observe_paged_prefill(
+                    await await_invocation(inv_fut))
+                self._note_chunk(pspan, reply)
         except BaseException as e:
-            pspan.set("error.type", type(e).__name__)
-            pspan.finish("error")
             self._lose_state(e)
             if isinstance(e, asyncio.CancelledError):
                 raise
             return
-        pspan.finish()
         self._to_free.clear()
         rec = inv_fut.record
         n = max(1, len(self.pending))
@@ -739,13 +769,11 @@ class EngineLoop:
         was already stamped at the prefill reply — migration latency shows
         up in per-token time, not time-to-first-token."""
         engine, live, free = self.engine, self.live, self._free
-        mspan = self._span("engine.migrate_out", rows=len(slots))
         try:
-            payloads = await loop.run_in_executor(
-                self.cpu, self._bound(mspan, engine.extract_rows), slots)
+            with self._phase("engine.migrate_out", rows=len(slots)) as mspan:
+                payloads = await loop.run_in_executor(
+                    self.cpu, self._bound(mspan, engine.extract_rows), slots)
         except BaseException as e:
-            mspan.set("error.type", type(e).__name__)
-            mspan.finish("error")
             for slot in slots:
                 row = live.pop(slot, None)
                 if row is not None:
@@ -757,7 +785,6 @@ class EngineLoop:
             if isinstance(e, asyncio.CancelledError):
                 raise
             return
-        mspan.finish()
         items = []
         for slot, payload in zip(slots, payloads):
             row = live.pop(slot)
@@ -782,19 +809,17 @@ class EngineLoop:
         if not take:
             return
         slots = [slot for slot, _ in take]
-        mspan = self._span("engine.migrate_in", rows=len(take))
         try:
-            if not live:
-                inv_fut, _ = await loop.run_in_executor(
-                    self.cpu, self._bound(mspan, engine.submit_admit),
-                    [], True)
-                engine.observe(await await_invocation(inv_fut))
-            await loop.run_in_executor(
-                self.cpu, self._bound(mspan, engine.insert_rows), slots,
-                [ent["entry"] for _, ent in take])
+            with self._phase("engine.migrate_in", rows=len(take)) as mspan:
+                if not live:
+                    inv_fut, _ = await loop.run_in_executor(
+                        self.cpu, self._bound(mspan, engine.submit_admit),
+                        [], True)
+                    engine.observe(await await_invocation(inv_fut))
+                await loop.run_in_executor(
+                    self.cpu, self._bound(mspan, engine.insert_rows), slots,
+                    [ent["entry"] for _, ent in take])
         except BaseException as e:
-            mspan.set("error.type", type(e).__name__)
-            mspan.finish("error")
             for slot, ent in take:
                 free.append(slot)
                 self._fail(ent["row"].fut, e, "row insert failed")
@@ -803,7 +828,6 @@ class EngineLoop:
             if isinstance(e, asyncio.CancelledError):
                 raise
             return
-        mspan.finish()
         for slot, ent in take:
             live[slot] = ent["row"]
         self.migrated_in += len(take)
@@ -968,7 +992,9 @@ class ContinuousBatcher:
         if self._closed:
             raise RuntimeError("batcher is closed")
         self._ensure_running()
-        fut = asyncio.get_running_loop().create_future()
+        loop = asyncio.get_running_loop()
+        fut = loop.create_future()
+        fut._t_submit = loop.time()
         self._queue.append((request, fut))
         self._arrived.set()
         return await fut

@@ -1,7 +1,11 @@
 """Observability plane (ISSUE 8): trace context on the wire, span
 stitching across the process boundary, metrics registry math, exporters,
-and the tracing-off overhead guard."""
+the JAX profiler sink, and the tracing-off overhead guard."""
+import asyncio
+import gc
+import glob
 import json
+import os
 
 import pytest
 
@@ -219,3 +223,194 @@ def test_disabled_tracing_makes_no_instrumentation_calls():
         f = sess.function(task_double, jax_traceable=False)
         assert f.submit(4).result() == 8
     assert t.calls == 0 and t.spans() == []
+
+
+# ------------------------------------------------------ the profiler sink ----
+
+PROGRAM_SPANS = {"engine.schedule", "engine.idle", "engine.prefill_chunk",
+                 "engine.decode_quantum", "worker.entry", "engine.sync",
+                 "py.gc"}
+
+
+@pytest.fixture(scope="module")
+def tiny_lm():
+    import jax
+    from repro.configs import get_smoke
+    from repro.models import build_model
+    cfg = get_smoke("smollm-360m")
+    params, _ = build_model(cfg).init(jax.random.PRNGKey(0))
+    return cfg, params
+
+
+def _paged_serve(cfg, params, during=None, paged=True):
+    """A ``threads`` paged serve: five prompts (two repeated, so the radix
+    index serves part of them), then after a pause, in which the loop
+    idles, one more.  ``during(start)`` brackets the second round.
+    ``paged=False`` serves the same from the slot arena and its prefix
+    store."""
+    import numpy as np
+    from repro.cloud import Session
+    from repro.runtime.server import LMServer, Request
+    from repro.serving import ContinuousBatcher
+    rng = np.random.default_rng(0)
+    prompts = [[int(t) for t in rng.integers(1, cfg.vocab_size, n)]
+               for n in (20, 40, 7)]
+    prompts += [list(prompts[0]), list(prompts[1])]
+    reqs = [Request(prompt=p, max_new=m)
+            for p, m in zip(prompts, (12, 9, 16, 5, 12))]
+
+    async def go(b):
+        comps = list(await asyncio.gather(*[b.submit(r) for r in reqs]))
+        await asyncio.sleep(0.05)
+        comps.append(await b.submit(Request(prompt=prompts[2], max_new=4)))
+        return comps
+
+    with Session("threads", os_threads=2) as sess:
+        server = LMServer(cfg, params, session=sess, max_new=16)
+
+        async def main():
+            async with ContinuousBatcher(server, max_batch=3, slots=1,
+                                         quantum=4, paged=paged,
+                                         block_size=4, prefill_budget=8,
+                                         arena_cap=64 if paged else None
+                                         ) as b:
+                await go(b)                      # compiles every program
+                if during:
+                    during(True)
+                comps = await go(b)
+                gc.collect()
+                if during:
+                    during(False)
+                return comps, b.stats
+
+        comps, stats = asyncio.run(main())
+        server.close(prune=False)
+    return reqs, comps, stats
+
+
+@pytest.fixture
+def counted(monkeypatch):
+    """Every TraceAnnotation the sink creates, by name."""
+    from jax.profiler import TraceAnnotation
+    made = []
+
+    class Counting(TraceAnnotation):
+        def __init__(self, name, **kw):
+            made.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(obs_trace, "_ANNOTATION", Counting)
+    return made
+
+
+def test_no_sink_on_makes_no_annotation_and_no_call(tiny_lm, counted):
+    """Neither the ring nor a profiler session: a whole paged serve creates
+    no annotation and engages the tracer nowhere."""
+    _paged_serve(*tiny_lm)
+    gc.collect()
+    assert counted == []
+    assert obs_trace.TRACER.calls == 0 and obs_trace.TRACER.spans() == []
+
+
+def test_profiler_alone_lands_every_span_in_the_trace(tiny_lm, counted,
+                                                      tmp_path):
+    """Tracing off, a profiler session capturing: the program's spans are
+    host events of the trace, attributes in their metadata, and the ring
+    sees nothing."""
+    import jax
+
+    def during(start):
+        if start:
+            jax.profiler.start_trace(str(tmp_path))
+        else:
+            jax.profiler.stop_trace()
+
+    _paged_serve(*tiny_lm, during=during)
+    assert obs_trace.TRACER.calls == 0 and obs_trace.TRACER.spans() == []
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                        recursive=True)
+    found: dict = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in PROGRAM_SPANS:
+                    found.setdefault(e.name, dict(e.stats))
+    assert set(found) == PROGRAM_SPANS
+    assert set(counted) == PROGRAM_SPANS
+    assert {"k", "rows"} <= set(found["engine.decode_quantum"])
+    assert {"prompt_tokens", "padded_tokens", "prefix_tokens"} \
+        <= set(found["engine.prefill_chunk"])
+    assert {"generation", "collected"} <= set(found["py.gc"])
+
+
+def test_ring_and_profiler_share_one_span(counted, tmp_path):
+    """With both sinks on, one handle feeds both: a ring span and a
+    profiler annotation of the same name and attributes."""
+    import jax
+    obs_trace.configure(sample=1.0)
+    root = obs_trace.TRACER.start_trace("engine.loop")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs_trace.TRACER.span("engine.sync", root.ctx, profile=True,
+                                   what="tokens") as sp:
+            sp.set("rows", 3)
+        unprofiled = obs_trace.TRACER.span("client.submit", root.ctx)
+        unprofiled.finish()
+    finally:
+        jax.profiler.stop_trace()
+    root.finish()
+    assert counted == ["engine.sync"]
+    ring = {s.name: s for s in obs_trace.TRACER.spans()}
+    assert ring["engine.sync"].attrs == {"what": "tokens", "rows": 3}
+    assert ring["engine.sync"].parent_id == root.ctx.span_id
+
+
+def test_gc_hook_takes_no_tracer_lock(counted, tmp_path):
+    """A collection can interrupt a thread that holds the tracer's locks.
+    With both sinks on and a context bound, the ``py.gc`` hook must take
+    neither lock, and records into the profiler alone."""
+    import threading
+    import jax
+    obs_trace.configure(sample=1.0)
+    root = obs_trace.TRACER.start_trace("engine.loop")
+    calls = obs_trace.TRACER.calls
+
+    def collection():
+        obs_trace._on_gc("start", {"generation": 2})
+        obs_trace._on_gc("stop", {"generation": 2, "collected": 5,
+                                  "uncollectable": 0})
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs_trace.TRACER._lock, obs_trace._id_lock:
+            t = threading.Thread(target=obs_trace.bound(root.ctx, collection),
+                                 daemon=True)
+            t.start()
+            t.join(10)
+            blocked = t.is_alive()
+    finally:
+        jax.profiler.stop_trace()
+    t.join()
+    assert not blocked
+    assert counted == ["py.gc"]
+    assert obs_trace.TRACER.calls == calls and obs_trace.TRACER.spans() == []
+
+
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "slot"])
+def test_prompt_tokens_and_queue_wait_are_counted(tiny_lm, paged):
+    """Every admitted prompt token is either prefilled or served from the
+    radix index (or the slot arena's prefix store), and both are counted;
+    the queue wait and TTFT start at submit, so the TTFT histogram sums at
+    least the admission-based ``Completion.ttft_ms``."""
+    reg = obs_metrics.REGISTRY
+    wait0 = reg.histogram("serve_queue_wait_ms").series()["count"]
+    ttft0 = reg.histogram("serve_ttft_ms").series()["sum"]
+    reqs, comps, stats = _paged_serve(*tiny_lm, paged=paged)
+    served = 2 * sum(len(r.prompt) for r in reqs) + 2 * len(reqs[2].prompt)
+    s = stats.summary()
+    assert s["prefill_tokens"] + s["prefix_tokens"] == served
+    assert s["prefix_tokens"] > 0
+    assert reg.histogram("serve_queue_wait_ms").series()["count"] \
+        - wait0 == 2 * (len(reqs) + 1)
+    assert reg.histogram("serve_ttft_ms").series()["sum"] - ttft0 \
+        >= sum(c.ttft_ms for c in comps)
