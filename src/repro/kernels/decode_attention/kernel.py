@@ -128,15 +128,17 @@ def flash_decode_bhgd(q, k, v, kv_len, kv_start=None, *, window=0, scale=None,
     )(_bounds(b, kv_len, kv_start), q, k, v)
 
 
-def _dec_paged_kernel(table_ref, bounds_ref, q_ref, k_ref, v_ref, o_ref,
-                      acc_ref, m_ref, l_ref, *, scale, window, block_size,
-                      table_width):
+def _dec_paged_kernel(layer_ref, table_ref, bounds_ref, q_ref, k_ref, v_ref,
+                      o_ref, acc_ref, m_ref, l_ref, *, scale, window,
+                      block_size, table_width):
     """Block-table flash-decode body: grid dim 2 walks the row's table.
 
     Identical running-softmax math to `_dec_kernel`; the only change is
     that tile j holds *logical* columns [j·bs, (j+1)·bs) gathered from
-    physical pool block `table[b, j]` by the BlockSpec index_map — dead
-    table entries point at block 0 and are masked out by kv_len anyway."""
+    physical pool block `table[b, j]` of layer `layer` by the BlockSpec
+    index_map — dead table entries point at block 0 and are masked out by
+    kv_len anyway."""
+    del layer_ref                        # read by the index_maps only
     b_ = pl.program_id(0)
     ik = pl.program_id(2)
     kv_start = bounds_ref[b_, 0]
@@ -161,41 +163,43 @@ def _dec_paged_kernel(table_ref, bounds_ref, q_ref, k_ref, v_ref, o_ref,
 
 @functools.partial(
     jax.jit, static_argnames=("window", "scale", "interpret"))
-def flash_decode_paged_bhgd(q, k_pool, v_pool, table, kv_len, kv_start=None,
-                            *, window=0, scale=None, interpret=False):
-    """Paged flash-decode: q (B,Hkv,G,D); k_pool/v_pool (NB,Hkv,BS,D);
-    table (B,T) int32 of pool block ids -> (B,Hkv,G,D).
+def flash_decode_paged_bhgd(q, k_pool, v_pool, layer, table, kv_len,
+                            kv_start=None, *, window=0, scale=None,
+                            interpret=False):
+    """Paged flash-decode: q (B,Hkv,G,D); k_pool/v_pool (L,NB,Hkv,BS,D),
+    every layer's pool stacked; ``layer`` int32 scalar; table (B,T) int32
+    of pool block ids -> (B,Hkv,G,D).
 
-    Row b's logical cache column c lives at pool[table[b, c // BS], :,
-    c % BS].  The table rides in as a scalar-prefetch operand so the K/V
-    BlockSpec index_maps can gather physical blocks per grid step; the
-    tile size IS the block size, so masking is byte-for-byte the
-    contiguous kernel's.  Unused table entries should be 0 (the reserved
-    trash block) — they are masked by kv_len but must still be valid ids."""
+    Row b's logical cache column c of layer l lives at pool[l, table[b, c
+    // BS], :, c % BS].  The layer and the table ride in as scalar-prefetch
+    operands so the K/V BlockSpec index_maps gather physical blocks of one
+    layer straight out of the stacked pool per grid step: nothing of pool
+    size is sliced or copied to feed the kernel.  The tile size IS the
+    block size, so masking is byte-for-byte the contiguous kernel's.
+    Unused table entries should be 0 (the reserved trash block) — they are
+    masked by kv_len but must still be valid ids."""
     b, hkv, g, d = q.shape
-    nb, _, bs, _ = k_pool.shape
+    _, _, _, bs, _ = k_pool.shape
     t = table.shape[1]
     scale = scale if scale is not None else d ** -0.5
+
+    def kv_map(b_, h, j, layer_ref, table_ref, bounds_ref):
+        return (layer_ref[0], table_ref[b_, j], h, 0, 0)
+
+    def q_map(b_, h, j, layer_ref, table_ref, bounds_ref):
+        return (b_, h, 0, 0)
 
     kernel = functools.partial(_dec_paged_kernel, scale=scale, window=window,
                                block_size=bs, table_width=t)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,          # table, bounds — SMEM, index_map-visible
+        num_scalar_prefetch=3,  # layer, table, bounds: SMEM, index_maps
         grid=(b, hkv, t),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d),
-                         lambda b_, h, j, table_ref, bounds_ref:
-                         (b_, h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, d),
-                         lambda b_, h, j, table_ref, bounds_ref:
-                         (table_ref[b_, j], h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, d),
-                         lambda b_, h, j, table_ref, bounds_ref:
-                         (table_ref[b_, j], h, 0, 0)),
+            pl.BlockSpec((1, 1, g, d), q_map),
+            pl.BlockSpec((None, 1, 1, bs, d), kv_map),
+            pl.BlockSpec((None, 1, 1, bs, d), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda b_, h, j, table_ref, bounds_ref:
-                               (b_, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, g, d), q_map),
         scratch_shapes=_scratch(g, d),
     )
     return pl.pallas_call(
@@ -203,5 +207,5 @@ def flash_decode_paged_bhgd(q, k_pool, v_pool, table, kv_len, kv_start=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         interpret=interpret,
-    )(table.astype(jnp.int32), _bounds(b, kv_len, kv_start), q, k_pool,
-      v_pool)
+    )(jnp.asarray(layer, jnp.int32).reshape(1), table.astype(jnp.int32),
+      _bounds(b, kv_len, kv_start), q, k_pool, v_pool)

@@ -2,10 +2,11 @@
 
 Two entry points:
 - ``decode_attention``      — contiguous (B, Skv, Hkv, D) cache.
-- ``decode_attention_paged``— block-pool cache (NB, BS, Hkv, D) addressed
-  through a per-row int32 block table (B, T); the jnp path gathers the
-  table into a contiguous view (bit-identical by construction), the
-  Pallas path walks the table in SMEM via scalar prefetch.
+- ``decode_attention_paged``— one layer of the stacked block pool
+  (L, NB, Hkv, BS, D) addressed through a per-row int32 block table
+  (B, T); the jnp path gathers the layer's table into a contiguous view
+  (bit-identical by construction), the Pallas path walks the table in
+  SMEM via scalar prefetch and reads the stacked pool in place.
 """
 from __future__ import annotations
 
@@ -43,33 +44,34 @@ def decode_attention(q, k, v, kv_len, *, window: int = 0,
     return out.reshape(b, hq, d)
 
 
-def decode_attention_paged(q, k_pool, v_pool, table, kv_len, *,
+def decode_attention_paged(q, k_pool, v_pool, table, kv_len, layer=0, *,
                            window: int = 0, scale: float | None = None,
                            kv_start=None, impl: str = "ref"):
-    """Paged decode attention.
+    """Paged decode attention over layer ``layer`` of a stacked pool.
 
-    q (B,Hq,D); k_pool/v_pool (NB,BS,Hkv,D); table (B,T) int32 of pool
-    block ids; kv_len (B,) -> (B,Hq,D).  Row b's logical column c is
-    pool[table[b, c // BS], c % BS]; entries past the row's length should
-    be 0 (the reserved trash block) so every gather stays in bounds.
+    q (B,Hq,D); k_pool/v_pool (L,NB,Hkv,BS,D); table (B,T) int32 of pool
+    block ids; kv_len (B,); layer an int or int32 scalar -> (B,Hq,D).
+    Row b's logical column c is pool[layer, table[b, c // BS], :, c % BS];
+    entries past the row's length should be 0 (the reserved trash block)
+    so every gather stays in bounds.
     """
     b, hq, d = q.shape
-    nb, bs, hkv, _ = k_pool.shape
+    _, _, hkv, bs, _ = k_pool.shape
     if impl in ("ref", "xla"):
         t = table.shape[1]
-        # gather the table into the contiguous layout and defer to the ref:
-        # table indexing is pure gather, so this IS the semantics the
-        # Pallas path must reproduce bit-for-bit.
-        kc = k_pool[table].reshape(b, t * bs, hkv, d)
-        vc = v_pool[table].reshape(b, t * bs, hkv, d)
-        return decode_attention_ref(q, kc, vc, kv_len, window=window,
-                                    scale=scale, kv_start=kv_start)
+        # gather the layer's table into the contiguous layout and defer to
+        # the ref: table indexing is pure gather, so this IS the semantics
+        # the Pallas path must reproduce bit-for-bit.
+        def view(pool):              # (B,T,Hkv,BS,D) -> (B,T·BS,Hkv,D)
+            return jnp.swapaxes(pool[layer, table], 2, 3).reshape(
+                b, t * bs, hkv, d)
+        return decode_attention_ref(q, view(k_pool), view(v_pool), kv_len,
+                                    window=window, scale=scale,
+                                    kv_start=kv_start)
     interpret = impl == "pallas_interpret"
     g = hq // hkv
-    qg = q.reshape(b, hkv, g, d)
-    kp = jnp.swapaxes(k_pool, 1, 2)                  # (NB,Hkv,BS,D)
-    vp = jnp.swapaxes(v_pool, 1, 2)
-    out = flash_decode_paged_bhgd(qg, kp, vp, table, kv_len, kv_start,
+    out = flash_decode_paged_bhgd(q.reshape(b, hkv, g, d), k_pool, v_pool,
+                                  layer, table, kv_len, kv_start,
                                   window=window, scale=scale,
                                   interpret=interpret)
     return out.reshape(b, hq, d)

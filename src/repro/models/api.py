@@ -389,9 +389,11 @@ def cache_shift_left(cfg: ModelConfig, arena, shift: int):
 # allocated, pinned at refcount 1, the landing zone for dead-row and
 # pad-position writes (always masked out of attention by kv_len).
 #
-# The device side is just two pool tensors (L, NB, BS, Hkv, D) updated by
-# the jitted model fns; everything below is HOST accounting (numpy), kept
-# in the worker's state-registry entry next to the pools.
+# The device side is just two pool tensors (L, NB, Hkv, BS, D) — the
+# paged decode kernel's own layout, a block's minor dims (BS, D) — that
+# the jitted model fns update in place; everything below is HOST
+# accounting (numpy), kept in the worker's state-registry entry next to
+# the pools.
 
 def paged_supported(cfg: ModelConfig) -> bool:
     """Families servable from a paged arena.  Attention families need the
@@ -406,10 +408,10 @@ def paged_supported(cfg: ModelConfig) -> bool:
 
 
 def paged_init_pool(cfg: ModelConfig, blocks: int, block_size: int):
-    """Zeroed K/V block pools: (L, NB, BS, Hkv, D) in the cache dtype.
+    """Zeroed K/V block pools: (L, NB, Hkv, BS, D) in the cache dtype.
     Block 0 is the trash block — part of the tensor, never handed out."""
     cdt = jnp.dtype(cfg.param_dtype)
-    shp = (cfg.n_layers, blocks, block_size, cfg.n_kv_heads, cfg.head_dim)
+    shp = (cfg.n_layers, blocks, cfg.n_kv_heads, block_size, cfg.head_dim)
     return {"k": jnp.zeros(shp, cdt), "v": jnp.zeros(shp, cdt)}
 
 

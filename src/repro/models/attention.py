@@ -165,69 +165,98 @@ def attn_decode(p, x, cache_k, cache_v, idx, *, window=0, rope_theta=0.0,
 # positions), and the masked-softmax reductions are placement/width
 # invariant as long as every gathered width stays a power of two.
 
-def attn_decode_paged(p, x, pool_k, pool_v, table, lens, live, *, window=0,
-                      rope_theta=0.0, impl="ref"):
-    """One-token attention against a block pool.
+def _pool_write(pool, layer, table, start, kv, n):
+    """Write one run of new K or V per row into layer ``layer`` of the
+    stacked pool (L,NB,Hkv,BS,D); returns the pool.
 
-    x (B,1,d); pool_k/pool_v (NB,BS,Hkv,D); table (B,T) int32;
-    lens (B,) tokens already resident per row; live (B,) bool.
+    table (R,T) int32 block tables; start (R,) the logical column where
+    each row's run begins; kv (R,C,Hkv,D) the runs, of which each row's
+    first ``n`` (R,) are real.  Every block a run can touch is gathered
+    whole, the run's real tokens are selected into it, and the blocks are
+    scattered back whole: a scatter whose window is a whole (Hkv,BS,D)
+    block keeps the pool in the kernel's layout, so XLA updates the
+    carried pool in place.  (Scattering single tokens, an (Hkv,D) window,
+    has XLA lay the pool out with Hkv minor to BS, and copy it back whole
+    for the kernel every layer.)  Rows with n == 0 and columns past the
+    table touch only the trash block 0."""
+    r, c = kv.shape[:2]
+    _, _, hkv, bs, d = pool.shape
+    t = table.shape[1]
+    nt = min(t, (c + bs - 2) // bs + 1)       # blocks a run of c can span
+    lb = start[:, None] // bs + jnp.arange(nt)                  # (R,nt)
+    ids = jnp.take_along_axis(table, jnp.minimum(lb, t - 1), axis=1)
+    ids = jnp.where((lb < t) & (n[:, None] > 0), ids, 0)
+    j = jnp.arange(nt * bs) - (start % bs)[:, None]     # run index per column
+    real = ((j >= 0) & (j < n[:, None])).reshape(r, nt, 1, bs, 1)
+    vals = kv[jnp.arange(r)[:, None], jnp.clip(j, 0, c - 1)]  # R,nt·BS,Hkv,D
+    vals = jnp.swapaxes(vals.reshape(r, nt, bs, hkv, d), 2, 3)
+    blocks = jnp.where(real, vals.astype(pool.dtype), pool[layer, ids])
+    return pool.at[layer, ids].set(blocks)
+
+
+def attn_decode_paged(p, x, pool_k, pool_v, layer, table, lens, live, *,
+                      window=0, rope_theta=0.0, impl="ref"):
+    """One-token attention of layer ``layer`` against the stacked pool.
+
+    x (B,1,d); pool_k/pool_v (L,NB,Hkv,BS,D), every layer's blocks;
+    layer an int or int32 scalar; table (B,T) int32; lens (B,) tokens
+    already resident per row; live (B,) bool.
 
     The new token is written at logical column ``lens`` — physically
-    ``pool[table[b, lens // BS], lens % BS]``.  Dead rows write to the
-    reserved trash block 0 (never read unmasked: their kv_len is 0, so
-    the kernel's l == 0 guard zeroes the whole row).
+    ``pool[layer, table[b, lens // BS], :, lens % BS]`` — into the pool
+    the caller carries, which the kernel then reads in place: nothing of
+    pool size is sliced or copied.  Dead rows write nothing (their kv_len
+    is 0, so the kernel's l == 0 guard zeroes the whole row).
     Returns (out, pool_k, pool_v)."""
-    b = x.shape[0]
-    bs = pool_k.shape[1]
     lens = lens.astype(jnp.int32)
     positions = lens[:, None]                       # right-dense: pos == len
     q, k, v = _project(p, x, positions, rope_theta=rope_theta,
                        mrope_sections=(), pos3d=None)
-    rows = jnp.arange(b)
-    blk = jnp.where(live, table[rows, lens // bs], 0)
-    off = lens % bs
-    pool_k = pool_k.at[blk, off].set(k[:, 0].astype(pool_k.dtype))
-    pool_v = pool_v.at[blk, off].set(v[:, 0].astype(pool_v.dtype))
+    n = live.astype(jnp.int32)                      # one token per live row
+    pool_k = _pool_write(pool_k, layer, table, lens, k, n)
+    pool_v = _pool_write(pool_v, layer, table, lens, v, n)
     kv_len = jnp.where(live, lens + 1, 0).astype(jnp.int32)
-    o = decode_attention_paged(q[:, 0], pool_k, pool_v, table, kv_len,
+    o = decode_attention_paged(q[:, 0], pool_k, pool_v, table, kv_len, layer,
                                window=window, impl=impl)
     out = jnp.einsum("bhk,hkd->bd", o, p["wo"])[:, None]
     return out, pool_k, pool_v
 
 
-def attn_prefill_paged(p, x, pool_k, pool_v, table, m, n_real, *, window=0,
-                       rope_theta=0.0, impl="ref"):
-    """One chunk of continued prefill against a block pool (B == 1).
+def attn_prefill_paged(p, x, pool_k, pool_v, layer, table, m, n_real, *,
+                       window=0, rope_theta=0.0, impl="ref"):
+    """One chunk of continued prefill of layer ``layer`` against the
+    stacked pool (B == 1).
 
     x (1,C,d) — the chunk's embeddings, real tokens in [0, n_real), the
     rest right-pad; ``m`` is how many tokens of this row the pool already
     holds, so chunk token j is logical column m + j.  K/V for real chunk
-    positions scatter into the row's table-mapped blocks (pad positions
-    go to the trash block); attention runs q_offset = m against the full
-    gathered table view, masked to kv_len = m + n_real.  Chaining calls
-    with growing ``m`` reproduces a monolithic prefill bit-for-bit.
+    positions are written into the row's table-mapped blocks of ``layer``
+    (pad positions are not written); attention runs q_offset = m against
+    the row's T blocks of that layer gathered into a contiguous view,
+    masked to kv_len = m + n_real.  Chaining calls with growing ``m``
+    reproduces a monolithic prefill bit-for-bit.
     Returns (out (1,C,d-model), pool_k, pool_v)."""
     _, c, _ = x.shape
-    bs = pool_k.shape[1]
+    _, _, hkv, bs, d = pool_k.shape
     t = table.shape[1]
     j = jnp.arange(c)
     positions = (m + j)[None, :]
     q, k, v = _project(p, x, positions, rope_theta=rope_theta,
                        mrope_sections=(), pos3d=None)
-    real = j < n_real
-    ti = jnp.where(real, (m + j) // bs, 0)          # clamp pad lookups
-    blk = jnp.where(real, table[0, ti], 0)
-    off = (m + j) % bs
-    pool_k = pool_k.at[blk, off].set(k[0].astype(pool_k.dtype))
-    pool_v = pool_v.at[blk, off].set(v[0].astype(pool_v.dtype))
-    kview = pool_k[table[0]].reshape(1, t * bs, *pool_k.shape[2:])
-    vview = pool_v[table[0]].reshape(1, t * bs, *pool_v.shape[2:])
+    start, n = jnp.reshape(m, (1,)), jnp.reshape(n_real, (1,))
+    pool_k = _pool_write(pool_k, layer, table, start, k, n)
+    pool_v = _pool_write(pool_v, layer, table, start, v, n)
+
+    def view(pool):                          # (T,Hkv,BS,D) -> (1,T·BS,Hkv,D)
+        return jnp.swapaxes(pool[layer, table[0]], 1, 2).reshape(
+            1, t * bs, hkv, d).astype(q.dtype)
+
     kv_len = jnp.full((1,), m + n_real, jnp.int32)
     # The Pallas prefill kernel has no kv_len bound, so a chunk attends
     # over its gathered view on the query-chunked jnp path on every
     # platform — the same code the CPU invariance tests pin.
-    o = attention(q, kview.astype(q.dtype), vview.astype(q.dtype),
-                  causal=True, window=window, q_offset=m, kv_len=kv_len,
+    o = attention(q, view(pool_k), view(pool_v), causal=True, window=window,
+                  q_offset=m, kv_len=kv_len,
                   impl="ref" if impl == "ref" else "xla")
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
     return out, pool_k, pool_v

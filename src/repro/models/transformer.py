@@ -14,6 +14,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..configs.base import ModelConfig
 from ..sharding import shard
@@ -263,28 +264,35 @@ def lm_decode_paged(p, cfg: ModelConfig, pool_k, pool_v, table, lens, live,
                     tokens, attn_impl: str = "ref"):
     """One decode step against a shared block pool.
 
-    tokens (B,1); pool_k/pool_v (L,NB,BS,Hkv,D); table (B,T) int32;
-    lens (B,) resident tokens per row; live (B,) bool.  Each row's new K/V
-    lands at logical column ``lens[b]`` through its table (dead rows write
-    the trash block).  Returns (logits (B,V), pool_k, pool_v) — per-row
-    lens/table bookkeeping is the host's job (block refcounts live there).
+    tokens (B,1); pool_k/pool_v (L,NB,Hkv,BS,D); table (B,T) int32;
+    lens (B,) resident tokens per row; live (B,) bool.  Each live row's
+    new K/V lands at logical column ``lens[b]`` through its table.  The
+    stacked pools are the layer loop's carry — each
+    layer writes its token into them and attends over them in place, so
+    no layer's pool is sliced out or stacked back.  Returns (logits (B,V),
+    pool_k, pool_v) — per-row lens/table bookkeeping is the host's job
+    (block refcounts live there).
     """
     x = _embed_in(p, cfg, tokens, None)
 
-    def body(x, xs):
-        lp, pk, pv = xs
+    def body(carry, xs):
+        x, pk, pv = carry
+        lp, layer = xs
         h = rms_norm(x, lp["ln1"], cfg.rms_eps)
-        h, pk, pv = attn_decode_paged(lp["attn"], h, pk, pv, table, lens,
-                                      live, window=cfg.window,
+        h, pk, pv = attn_decode_paged(lp["attn"], h, pk, pv, layer, table,
+                                      lens, live, window=cfg.window,
                                       rope_theta=cfg.rope_theta,
                                       impl=attn_impl)
         x = x + h
         h = rms_norm(x, lp["ln2"], cfg.rms_eps)
         h, _ = _ffn(lp, cfg, h, dropless=True)
-        return x + h, (pk, pv)
+        return (x + h, pk, pv), None
 
-    x, (pk, pv) = scan_layers(body, x, (p["layers"], pool_k, pool_v),
-                              use_scan=cfg.scan_layers)
+    # the layer index rides beside each layer's params: a traced int32
+    # under lax.scan, a plain integer when the loop is unrolled
+    xs = (p["layers"], np.arange(cfg.n_layers, dtype=np.int32))
+    (x, pk, pv), _ = scan_layers(body, (x, pool_k, pool_v), xs,
+                                 use_scan=cfg.scan_layers)
     logits = _logits(p, cfg, x[:, -1])
     return logits, pk, pv
 
@@ -295,27 +303,30 @@ def lm_prefill_paged_chunk(p, cfg: ModelConfig, tokens, pool_k, pool_v,
 
     tokens (1,C) right-padded, n_real real; ``m`` tokens of the row are
     already resident in the pool, so this chunk covers logical columns
-    [m, m + n_real).  Returns (last-real-token logits (1,V), pools).
-    Chaining chunks with growing m reproduces a monolithic prefill's
-    logits and cache bit-for-bit — that is the chunked-prefill contract
-    the invariance matrix pins.
+    [m, m + n_real).  The stacked pools are the layer loop's carry, as in
+    :func:`lm_decode_paged`.  Returns (last-real-token logits (1,V),
+    pools).  Chaining chunks with growing m reproduces a monolithic
+    prefill's logits and cache bit-for-bit — that is the chunked-prefill
+    contract the invariance matrix pins.
     """
     x = _embed_in(p, cfg, tokens, None)
 
-    def body(x, xs):
-        lp, pk, pv = xs
+    def body(carry, xs):
+        x, pk, pv = carry
+        lp, layer = xs
         h = rms_norm(x, lp["ln1"], cfg.rms_eps)
-        h, pk, pv = attn_prefill_paged(lp["attn"], h, pk, pv, table, m,
-                                       n_real, window=cfg.window,
+        h, pk, pv = attn_prefill_paged(lp["attn"], h, pk, pv, layer, table,
+                                       m, n_real, window=cfg.window,
                                        rope_theta=cfg.rope_theta,
                                        impl=attn_impl)
         x = x + h
         h = rms_norm(x, lp["ln2"], cfg.rms_eps)
         h, _ = _ffn(lp, cfg, h, dropless=True)
-        return x + h, (pk, pv)
+        return (x + h, pk, pv), None
 
-    x, (pk, pv) = scan_layers(body, x, (p["layers"], pool_k, pool_v),
-                              use_scan=cfg.scan_layers)
+    xs = (p["layers"], np.arange(cfg.n_layers, dtype=np.int32))
+    (x, pk, pv), _ = scan_layers(body, (x, pool_k, pool_v), xs,
+                                 use_scan=cfg.scan_layers)
     last = jax.lax.dynamic_slice(x, (0, n_real - 1, 0), (1, 1, x.shape[-1]))
     logits = _logits(p, cfg, last[:, 0])
     return logits, pk, pv

@@ -159,19 +159,25 @@ def test_paged_arena_pool_exhaustion_raises():
 
 # ----------------------------------------- block-table decode attention --
 
-def _as_pool(k, v, bs):
-    """Contiguous (B,Skv,Hkv,D) caches -> block pool + table such that the
-    paged gather reconstructs them exactly (block 0 = trash)."""
+def _as_pool(k, v, bs, layers=1, layer=0):
+    """Contiguous (B,Skv,Hkv,D) caches -> stacked block pools
+    (layers,NB,Hkv,BS,D) + table such that the paged gather of ``layer``
+    reconstructs them exactly (block 0 = trash; other layers hold noise,
+    so reading the wrong layer shows)."""
     b, skv, hkv, d = k.shape
     t = skv // bs
-    pool_k = np.zeros((1 + b * t, bs, hkv, d), k.dtype)
-    pool_v = np.zeros_like(pool_k)
+    noise = np.random.default_rng(layers)
+    shape = (layers, 1 + b * t, hkv, bs, d)
+    pool_k = noise.normal(size=shape).astype(k.dtype)
+    pool_v = noise.normal(size=shape).astype(v.dtype)
+    pool_k[layer] = 0
+    pool_v[layer] = 0
     table = np.zeros((b, t), np.int32)
     for r in range(b):
         for c in range(t):
             bid = 1 + r * t + c
-            pool_k[bid] = k[r, c * bs:(c + 1) * bs]
-            pool_v[bid] = v[r, c * bs:(c + 1) * bs]
+            pool_k[layer, bid] = k[r, c * bs:(c + 1) * bs].swapaxes(0, 1)
+            pool_v[layer, bid] = v[r, c * bs:(c + 1) * bs].swapaxes(0, 1)
             table[r, c] = bid
     return jnp.asarray(pool_k), jnp.asarray(pool_v), jnp.asarray(table)
 
@@ -183,9 +189,9 @@ def test_paged_decode_matches_contiguous(impl):
     k = np.asarray(RNG.normal(size=(b, skv, hkv, d)), np.float32)
     v = np.asarray(RNG.normal(size=(b, skv, hkv, d)), np.float32)
     kv_len = jnp.asarray([32, 17, 5], jnp.int32)
-    pool_k, pool_v, table = _as_pool(k, v, bs)
+    pool_k, pool_v, table = _as_pool(k, v, bs, layers=3, layer=1)
     ref = decode_attention_ref(q, jnp.asarray(k), jnp.asarray(v), kv_len)
-    out = decode_attention_paged(q, pool_k, pool_v, table, kv_len,
+    out = decode_attention_paged(q, pool_k, pool_v, table, kv_len, 1,
                                  impl=impl)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -219,11 +225,11 @@ def test_paged_decode_scrambled_table_and_trash_tail():
     kv_len = jnp.asarray([20, 9], jnp.int32)
     pool_k, pool_v, table = _as_pool(k, v, bs)
     # scramble: reverse the physical pool, remap the table accordingly
-    perm = np.arange(pool_k.shape[0])[::-1].copy()
+    perm = np.arange(pool_k.shape[1])[::-1].copy()
     perm[perm == 0], perm[0] = perm[0], 0        # keep trash at 0... swap
     inv = np.argsort(perm)
-    s_pool_k = jnp.asarray(np.asarray(pool_k)[perm])
-    s_pool_v = jnp.asarray(np.asarray(pool_v)[perm])
+    s_pool_k = jnp.asarray(np.asarray(pool_k)[:, perm])
+    s_pool_v = jnp.asarray(np.asarray(pool_v)[:, perm])
     s_table = jnp.asarray(inv[np.asarray(table)].astype(np.int32))
     base = decode_attention_paged(q, pool_k, pool_v, table, kv_len,
                                   impl="ref")
@@ -275,6 +281,145 @@ def test_decode_attention_ragged_skv_not_block_multiple():
     assert bool(jnp.all(jnp.isfinite(pal)))
     np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+# ------------------------------- stacked pool carried through the layers --
+# The engine's paged programs carry the stacked (L,NB,Hkv,BS,D) pool
+# through the layer loop and write and attend in place.  The reference
+# below moves the pool as a per-layer program would: slice each layer's
+# pool out, write the new K/V, attend over the row's gathered blocks,
+# stack the layers back.  Tokens and pools must agree bit for bit; block
+# 0 (trash, never read unmasked) is left out of the pool comparison.
+
+def _ref_layers(params, cfg, x, pool_k, pool_v, layer_fn):
+    """The layer loop with each layer's pool sliced in as the scan's
+    input and stacked back as its output."""
+    from repro.models.layers import rms_norm
+    from repro.models.transformer import _ffn
+
+    def body(x, xs):
+        lp, pk, pv = xs
+        h, pk, pv = layer_fn(lp["attn"], rms_norm(x, lp["ln1"], cfg.rms_eps),
+                             pk, pv)
+        x = x + h
+        h, _ = _ffn(lp, cfg, rms_norm(x, lp["ln2"], cfg.rms_eps),
+                    dropless=True)
+        return x + h, (pk, pv)
+
+    x, (pk, pv) = jax.lax.scan(body, x, (params["layers"], pool_k, pool_v))
+    return x, pk, pv
+
+
+def _ref_view(pool, row_table):
+    """One layer's (NB,Hkv,BS,D) pool, one row's table -> (1,T·BS,Hkv,D)."""
+    t, (_, hkv, bs, d) = row_table.shape[0], pool.shape
+    return jnp.swapaxes(pool[row_table], 1, 2).reshape(1, t * bs, hkv, d)
+
+
+def _ref_decode_step(params, cfg, pool_k, pool_v, table, lens, live, tok):
+    from repro.models.attention import _project
+    from repro.models.transformer import _embed_in, _logits
+
+    b, bs = table.shape[0], pool_k.shape[3]
+    blk = jnp.where(live, table[jnp.arange(b), lens // bs], 0)
+    kv_len = jnp.where(live, lens + 1, 0)
+
+    def layer_fn(p, h, pk, pv):
+        q, k, v = _project(p, h, lens[:, None], rope_theta=cfg.rope_theta,
+                           mrope_sections=(), pos3d=None)
+        pk = pk.at[blk, :, lens % bs].set(k[:, 0].astype(pk.dtype))
+        pv = pv.at[blk, :, lens % bs].set(v[:, 0].astype(pv.dtype))
+        kc = jnp.concatenate([_ref_view(pk, r) for r in table])
+        vc = jnp.concatenate([_ref_view(pv, r) for r in table])
+        o = decode_attention_ref(q[:, 0], kc, vc, kv_len)
+        return jnp.einsum("bhk,hkd->bd", o, p["wo"])[:, None], pk, pv
+
+    x, pk, pv = _ref_layers(params, cfg, _embed_in(params, cfg, tok, None),
+                            pool_k, pool_v, layer_fn)
+    return _logits(params, cfg, x[:, -1]), pk, pv
+
+
+def _ref_chunk(params, cfg, pool_k, pool_v, tokens, table, m, n_real):
+    from repro.kernels.flash_attention import attention
+    from repro.models.attention import _project
+    from repro.models.transformer import _embed_in, _logits
+
+    c, bs = tokens.shape[1], pool_k.shape[3]
+    j = jnp.arange(c)
+    real = j < n_real
+    blk = jnp.where(real, table[0, jnp.where(real, (m + j) // bs, 0)], 0)
+
+    def layer_fn(p, h, pk, pv):
+        q, k, v = _project(p, h, (m + j)[None], rope_theta=cfg.rope_theta,
+                           mrope_sections=(), pos3d=None)
+        pk = pk.at[blk, :, (m + j) % bs].set(k[0].astype(pk.dtype))
+        pv = pv.at[blk, :, (m + j) % bs].set(v[0].astype(pv.dtype))
+        o = attention(q, _ref_view(pk, table[0]).astype(q.dtype),
+                      _ref_view(pv, table[0]).astype(q.dtype), causal=True,
+                      q_offset=m, kv_len=jnp.full((1,), m + n_real),
+                      impl="xla")
+        return jnp.einsum("bshk,hkd->bsd", o, p["wo"]), pk, pv
+
+    x, pk, pv = _ref_layers(params, cfg, _embed_in(params, cfg, tokens, None),
+                            pool_k, pool_v, layer_fn)
+    logits = _logits(params, cfg, x[:, n_real - 1])
+    return jnp.argmax(logits, -1).astype(jnp.int32), pk, pv
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_paged_programs_match_a_per_layer_pool_loop(dtype):
+    """`_paged_chunk_fn` chained over two rows' prompts, then
+    `_paged_decode_fn` for a quantum of 8 with one dead row: the same
+    first tokens, decoded tokens and pools as the per-layer reference."""
+    from repro.configs import get_smoke
+    from repro.models import build_model
+    from repro.runtime import engine
+
+    cfg = get_smoke("smollm-360m").replace(param_dtype=dtype,
+                                           compute_dtype=dtype)
+    params, _ = build_model(cfg).init(jax.random.PRNGKey(3))
+    rows, t, bs, k = 3, 4, 4, 8
+    pool = paged_init_pool(cfg, 1 + rows * t, bs)
+    ids = np.random.default_rng(5).permutation(np.arange(1, 1 + rows * t))
+    table = ids.reshape(rows, t).astype(np.int32)
+    table[2] = 0                                   # row 2 holds nothing
+    prompts = {0: [5, 17, 3, 99, 42, 7, 8], 1: [11, 12, 13, 14, 15]}
+    chunks = [(0, 0, 4), (1, 0, 8), (0, 4, 4)]     # (row, done, width)
+    ref_chunk = jax.jit(_ref_chunk, static_argnums=1)
+    ref_step = jax.jit(_ref_decode_step, static_argnums=1)
+
+    got, ref = (pool["k"], pool["v"]), (pool["k"], pool["v"])
+    firsts = {}
+    for row, done, width in chunks:
+        real = prompts[row][done:done + width]
+        toks = np.full((1, width), cfg.pad_id, np.int32)
+        toks[0, :len(real)] = real
+        args = (toks, table[row:row + 1], jnp.int32(done),
+                jnp.int32(len(real)))
+        first, *got = engine._paged_chunk_fn(cfg, width)(params, *got, *args)
+        want, *ref = ref_chunk(params, cfg, *ref, *args)
+        assert int(first[0]) == int(want[0])
+        firsts[row] = int(first[0])
+
+    lens = np.array([7, 5, 0], np.int32)
+    live = np.array([True, True, False])
+    last = np.array([firsts[0], firsts[1], cfg.pad_id], np.int32)
+    pk, pv, nxt, toks = engine._paged_decode_fn(cfg, k)(
+        params, *got, table, lens, live, last)
+
+    tok = jnp.asarray(np.where(live, last, cfg.pad_id)[:, None])
+    want = []
+    for _ in range(k):
+        logits, *ref = ref_step(params, cfg, *ref, table, lens, live, tok)
+        tok = jnp.where(live, jnp.argmax(logits, -1), cfg.pad_id)[:, None]
+        want.append(np.asarray(tok[:, 0]))
+        lens = lens + live
+    want = np.stack(want, axis=1)
+    np.testing.assert_array_equal(np.asarray(toks), want)
+    np.testing.assert_array_equal(np.asarray(nxt), want[:, -1])
+    for a, b in zip((pk, pv), ref):
+        np.testing.assert_array_equal(np.asarray(a)[:, 1:],
+                                      np.asarray(b)[:, 1:])
 
 
 # -------------------------------------- paged serving invariance matrix --
