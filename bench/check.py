@@ -7,7 +7,11 @@ each prompt followed by its served tokens.  For each served token the gap
 is how far its reference logit lies below the reference's best logit at
 that position; greedy decoding served the best token, so a sound program
 reads only rounding there.  The widest gap over the sample is held to the
-cell's limit (``bench/limits/<cell>.json``).
+cell's limit (``bench/limits/<cell>.json``), or, where the limit names a
+``quantile``, that quantile of the sample's per-token gaps: a family whose
+router picks its top-k experts from near-equal scores serves a few tokens
+after a choice that rounding flipped, and those read wide gaps in a sound
+run (PERF.md, section 6, PR 16).
 
 The control (``control=True``, never in a benchmark run) puts the plain
 reference computed in float8 in the program's place: at each position of
@@ -17,11 +21,13 @@ the same row and limit, and the run must come out not correct.
 from __future__ import annotations
 
 import functools
-import importlib
+import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+import references
 
 SAMPLE_REQUESTS = 8      # the longest finished request and 7 drawn
 MIN_TOKENS = 256         # served tokens a sample must hold
@@ -41,9 +47,9 @@ def sample(recs, seed: int) -> list:
 
 
 @functools.lru_cache(maxsize=8)
-def _gap_fns(reference: str, config_key: tuple):
-    ref = importlib.import_module(f"references.{reference}")
-    config = dict(config_key)
+def _gap_fns(config_key: str):
+    config = json.loads(config_key)
+    ref = references.family(config)
 
     def gap(logits, targets):
         got = jnp.take_along_axis(logits, jnp.maximum(targets, 0)[:, None],
@@ -63,14 +69,13 @@ def _gap_fns(reference: str, config_key: tuple):
     return served, with_control
 
 
-def gaps(params, config: dict, capacity: int, recs, control: bool) -> dict:
-    """Widest reference-logit gap of the served tokens over ``recs`` and,
-    with ``control``, of the float8 reference's first choices at the same
-    positions."""
-    key = tuple(sorted((k, v) for k, v in config.items()
-                       if not isinstance(v, (dict, list))))
-    served_fn, control_fn = _gap_fns(config["reference"], key)
-    worst, worst_ctl, n = 0.0, 0.0, 0
+def gaps(params, config: dict, capacity: int, recs, control: bool,
+         quantile: float = 1.0) -> dict:
+    """The ``quantile`` (1: the widest) of the reference-logit gaps of the
+    served tokens over ``recs`` and, with ``control``, of the float8
+    reference's first choices at the same positions."""
+    served_fn, control_fn = _gap_fns(json.dumps(config, sort_keys=True))
+    served, ctl, n = [], [], 0
     for r in recs:
         seq = list(r.prompt) + list(r.tokens[:-1])
         lo, hi = len(r.prompt) - 1, len(r.prompt) - 1 + len(r.tokens)
@@ -81,13 +86,25 @@ def gaps(params, config: dict, capacity: int, recs, control: bool) -> dict:
         if control:
             g, c = (np.asarray(x)[lo:hi]
                     for x in control_fn(params, tokens, targets))
-            worst_ctl = max(worst_ctl, float(c.max()))
+            ctl.append(c)
         else:
             g = np.asarray(served_fn(params, tokens, targets))[lo:hi]
-        worst = max(worst, float(g.max()))
+        served.append(g)
         n += hi - lo
-    return {"served": worst, "control": worst_ctl if control else None,
+
+    def q(parts):
+        return float(np.quantile(np.concatenate(parts), quantile)) \
+            if parts else 0.0
+
+    return {"served": q(served), "control": q(ctl) if control else None,
             "tokens": n, "requests": len(recs)}
+
+
+def gap_row(limits: dict) -> tuple[str, float]:
+    """The name of the gap's row and the quantile it reads: the widest gap
+    unless the cell's limit names a quantile."""
+    q = float(limits["greedy_gap"].get("quantile", 1.0))
+    return ("greedy_gap_max" if q == 1 else f"greedy_gap_p{100 * q:g}"), q
 
 
 def _row(value, limit, ok) -> dict:
@@ -96,14 +113,15 @@ def _row(value, limit, ok) -> dict:
 
 def judge(params, cell, recs, run: dict, seed: int,
           control: bool = False) -> tuple[dict, float]:
-    """Every number compared, beside its limit; and the program's own widest
-    gap, which with ``control`` is not the one compared."""
+    """Every number compared, beside its limit; and the program's own gap,
+    which with ``control`` is not the one compared."""
     picked = sample(recs, seed)
-    g = gaps(params, cell.config, cell.capacity, picked, control)
+    row, quantile = gap_row(cell.limits)
+    g = gaps(params, cell.config, cell.capacity, picked, control, quantile)
     gap = g["control"] if control else g["served"]
     limit = cell.limits["greedy_gap"]["limit"]
     return {
-        "greedy_gap_max": _row(gap, limit, gap <= limit),
+        row: _row(gap, limit, gap <= limit),
         "checked_tokens_min": _row(g["tokens"], MIN_TOKENS,
                                    g["tokens"] >= MIN_TOKENS),
         "failed_requests_max": _row(len(run["errors"]), 0,

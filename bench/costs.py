@@ -1,16 +1,18 @@
 """Operations and bytes that a decode step must do, from the configuration.
 
-Counted from the model's published shapes and the live lengths, never from
-what an implementation pads to: a kernel that reads dead tiles or a step
-that copies its pool does more work than this, and shows it as a lower
-share of the peak.  All weights and KV are bfloat16 (2 bytes).
+The counts come from the configuration's family (``references.family``):
+its published shapes and the live lengths, never what an implementation
+pads to.  A kernel that reads dead tiles or a step that copies its pool does
+more work than this, and shows it as a lower share of the peak.
 """
 from __future__ import annotations
 
 import json
+from collections import Counter
 from pathlib import Path
 
-BYTES = 2
+import references
+
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
 
 
@@ -25,39 +27,32 @@ def peaks_for(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def _dims(cfg: dict):
-    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
-    return (cfg["num_hidden_layers"], d, h, cfg["num_key_value_heads"],
-            cfg.get("head_dim", d // h), cfg["intermediate_size"],
-            cfg["vocab_size"])
-
-
 def matmul_params(cfg: dict) -> int:
-    """Weights every token multiplies by: the layers' projections and the
-    output head (the embedding lookup is a gather, not a multiply)."""
-    L, d, h, hkv, dh, f, v = _dims(cfg)
-    per_layer = d * h * dh + 2 * d * hkv * dh + h * dh * d + 3 * d * f
-    return L * per_layer + v * d
+    """Weights every token multiplies by."""
+    return references.family(cfg).matmul_flops_per_token(cfg) // 2
+
+
+def step_weight_bytes(cfg: dict, rows: float) -> float:
+    """Bytes of weights a decode step of ``rows`` live rows must read."""
+    return references.family(cfg).step_weight_bytes(cfg, rows)
 
 
 def kv_bytes_per_token(cfg: dict) -> int:
-    L, _, _, hkv, dh, _, _ = _dims(cfg)
-    return L * 2 * hkv * dh * BYTES
+    """Bytes of keys and values that one cached token holds, all layers."""
+    return references.family(cfg).attention_work(cfg, 1)[1]
 
 
-def attention_flops(cfg: dict, kv_len: int) -> int:
-    """Scores and weighted sum of one query token over ``kv_len`` keys, all
-    layers."""
-    L, _, h, _, dh, _, _ = _dims(cfg)
-    return L * 4 * h * dh * kv_len
-
-
-def paged_kernel_work(cfg: dict, kv_tokens: int) -> tuple[int, int]:
+def paged_kernel_work(cfg: dict, kv_lens: dict) -> tuple[int, int]:
     """(flops, bytes) that the paged decode-attention kernel must do, over
-    all layers, for query tokens whose live key counts sum to
-    ``kv_tokens``."""
-    return attention_flops(cfg, kv_tokens), kv_tokens * kv_bytes_per_token(
-        cfg)
+    all layers, for query tokens at the live key counts ``kv_lens``
+    ({live length: query tokens})."""
+    work = references.family(cfg).attention_work
+    flops = nbytes = 0
+    for kv_len, n in kv_lens.items():
+        f, b = work(cfg, kv_len)
+        flops += n * f
+        nbytes += n * b
+    return flops, nbytes
 
 
 def roofline_s(flops: float, nbytes: float, peaks: dict) -> tuple[float, str]:
@@ -70,14 +65,18 @@ def roofline_s(flops: float, nbytes: float, peaks: dict) -> tuple[float, str]:
 def decode_work(cfg: dict, records, t0: float, t1: float) -> dict:
     """Useful work of the decode steps that produced the tokens delivered
     in [t0, t1): the token at index j >= 1 of a request came out of a step
-    that read prompt + j keys per layer (index 0 comes out of prefill)."""
-    tokens = kv = 0
+    that read prompt + j keys (index 0 comes out of prefill).  ``attention``
+    is the paged kernel's (flops, bytes) for them."""
+    kv_lens: Counter = Counter()
     for r in records:
         base = len(r.prompt)
         for j, s in enumerate(r.stamps):
             if j and t0 <= s < t1:
-                tokens += 1
-                kv += base + j
-    return {"tokens": tokens, "kv_tokens": kv,
-            "flops": 2 * matmul_params(cfg) * tokens
-            + attention_flops(cfg, 1) * kv}
+                kv_lens[base + j] += 1
+    tokens = sum(kv_lens.values())
+    attention = paged_kernel_work(cfg, kv_lens)
+    return {"tokens": tokens,
+            "kv_tokens": sum(n * c for n, c in kv_lens.items()),
+            "attention": attention,
+            "flops": references.family(cfg).matmul_flops_per_token(cfg)
+            * tokens + attention[0]}

@@ -12,6 +12,7 @@ program's default.
 from __future__ import annotations
 
 import asyncio
+import dataclasses
 import importlib.util
 import json
 import sys
@@ -29,6 +30,7 @@ CACHE_DIR = ROOT / ".jax_cache"
 import check  # noqa: E402  (bench/ is on sys.path: run.py's directory)
 import costs  # noqa: E402
 import devtrace  # noqa: E402
+import references  # noqa: E402
 import traffic  # noqa: E402
 import weights  # noqa: E402
 
@@ -87,20 +89,14 @@ def load_cell(name: str, doc: dict | None = None) -> Cell:
 
 def program_config(config: dict):
     """The program's ModelConfig for a configuration file: its registry
-    entry with every size and constant the file states put in."""
+    entry with the fields that the configuration's family reads from the
+    file put in (a nested group replaced field by field)."""
     from repro.configs import get_config
-    return get_config(config["program_model"]).replace(
-        n_layers=config["num_hidden_layers"], d_model=config["hidden_size"],
-        n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"],
-        head_dim=weights.sizes(config)["D"],
-        d_ff=config["intermediate_size"], vocab_size=config["vocab_size"],
-        rope_theta=float(config["rope_theta"]),
-        rms_eps=float(config["rms_norm_eps"]),
-        tie_embeddings=bool(config["tie_word_embeddings"]),
-        qkv_bias=bool(config.get("attention_bias")),
-        param_dtype=config["torch_dtype"],
-        compute_dtype=config["torch_dtype"], window=0)
+    base = get_config(config["program_model"])
+    fields = references.family(config).program_fields(config)
+    return base.replace(**{
+        k: dataclasses.replace(getattr(base, k), **v)
+        if isinstance(v, dict) else v for k, v in fields.items()})
 
 
 # ------------------------------------------------------ compile events --

@@ -15,6 +15,5 @@ def read(win):
     work = costs.decode_work(win.config, win.records, win.t0, win.t1)
     if t <= 0 or not work["tokens"]:
         return None
-    flops, nbytes = costs.paged_kernel_work(win.config, work["kv_tokens"])
-    least, _ = costs.roofline_s(flops, nbytes, win.peaks)
+    least, _ = costs.roofline_s(*work["attention"], win.peaks)
     return 100.0 * least / t
